@@ -8,7 +8,8 @@ engine's throughput axes:
 * ``fleet_throughput`` — the B x devices axes of the fleet engine
   (core/fleet.py): fleet vs batched engine at 1 device in-process, and
   device scaling on a forced-CPU multi-device mesh in a subprocess (this
-  process is pinned to one device).  The scaling axis uses a wide batch
+  process is pinned to one device; the CPU subprocess runs only when this
+  process is on the CPU as well).  The scaling axis uses a wide batch
   (B >> devices): the per-slot math vectorises across B on one core, so
   sharding only wins wall-clock once per-step work dominates scan-step
   overhead.
@@ -71,7 +72,8 @@ engine's throughput axes:
   equality — then reports the lane-engine rate (slots x lanes/sec, the
   guarded key) and the joint DP's wall time (informational).
 * ``multihost_scaling`` — the process axis of the fleet engine, FULL mode
-  only (``--fast`` emits a skip-marker row with null ratios: the cluster
+  on the CPU only (``--fast`` and a process on an accelerator emit a row
+  with null ratios: the cluster
   spawn + two-leg compile dominates a fast run, and the cross-process
   bit-equality claim stays covered by tests/test_multihost.py): a
   2-process local JAX cluster (``sharding.distributed.run_local_cluster``,
@@ -213,13 +215,21 @@ def _fleet_scaling_main(B, T, reps):
                       "scaling_vs_1dev": t_1 / t_n}))
 
 
+def _cpu_child_legs_allowed() -> bool:
+    """The forced-CPU child legs (device scaling, the process cluster)
+    measure the CPU backend.  They run only when this process is itself on
+    the CPU: a parent that holds an accelerator keeps it, and a child there
+    would quietly measure the host instead."""
+    return jax.default_backend() == "cpu"
+
+
 def _fleet_scaling_subprocess(B, T, reps, devices):
     env = dict(os.environ)
     # append: keep any reproducibility flags the caller set
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={devices}"
                         ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
@@ -228,8 +238,9 @@ def _fleet_scaling_subprocess(B, T, reps, devices):
         env=env, capture_output=True, text=True, timeout=900,
         cwd=os.path.join(os.path.dirname(__file__), ".."))
     if out.returncode != 0:
-        return None, (out.stderr or out.stdout).strip()[-400:]
-    return json.loads(out.stdout.strip().splitlines()[-1]), None
+        raise RuntimeError("fleet scaling leg failed: "
+                           + (out.stderr or out.stdout).strip()[-400:])
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def fleet_throughput(B=64, T=4096, reps=5, seed=0,
@@ -240,7 +251,9 @@ def fleet_throughput(B=64, T=4096, reps=5, seed=0,
     The 1-device comparison reuses ``hosting_batch_throughput``'s exact
     workload (``_workload_costs`` + ``_workload_traces``) so the two rows
     are directly comparable; the scaling run uses the wide
-    [scale_B, scale_T] batch in a forced-``scale_devices``-CPU subprocess.
+    [scale_B, scale_T] batch in a forced-``scale_devices``-CPU subprocess,
+    only when this process is on the CPU too (``scaling_vs_1dev`` is None
+    otherwise).  A failed scaling leg fails the row.
     """
     from repro.core.costs import HostingGrid
     from repro.core.fleet import FleetBatch
@@ -272,15 +285,14 @@ def fleet_throughput(B=64, T=4096, reps=5, seed=0,
         "scale_B": scale_B, "scale_T": scale_T,
         "scale_devices": scale_devices,
     }
-    scaling, err = _fleet_scaling_subprocess(scale_B, scale_T, max(3, reps // 2),
-                                             scale_devices)
-    if scaling is None:
+    if not _cpu_child_legs_allowed():
         row["scaling_vs_1dev"] = None
-        row["scaling_error"] = err
-    else:
-        row["scaling_vs_1dev"] = scaling["scaling_vs_1dev"]
-        row["fleet_slots_instances_per_sec_multidev"] = \
-            scaling["slots_per_sec_ndev"]
+        return row
+    scaling = _fleet_scaling_subprocess(scale_B, scale_T, max(3, reps // 2),
+                                        scale_devices)
+    row["scaling_vs_1dev"] = scaling["scaling_vs_1dev"]
+    row["fleet_slots_instances_per_sec_multidev"] = \
+        scaling["slots_per_sec_ndev"]
     return row
 
 
@@ -612,29 +624,25 @@ def multihost_scaling(B=512, T=4096, chunk=1024, reps=3):
     legs in subprocess workers (identical environment; this process's JAX
     runtime stays single-process).  Asserts the gathered global totals are
     bit-identical across legs; reports aggregate slots/sec both ways and
-    the scaling ratio.  A cluster failure is recorded in
-    ``multihost_error`` (visible in the row / --json), not an exception —
-    same convention as ``fleet_throughput``'s scaling subprocess."""
+    the scaling ratio.  The legs are CPU workers, so a parent that holds an
+    accelerator skips them (explicit nulls, which check_regression skips
+    with a note); a failed leg fails the row."""
     from repro.sharding import distributed
     argv = ["-m", "benchmarks.kernel_bench", "--multihost-worker",
             str(B), str(T), str(chunk), str(reps)]
     root = os.path.join(os.path.dirname(__file__), "..")
     row = {"name": "multihost_scaling", "B": B, "T": T, "chunk": chunk,
            "n_processes": 2}
-    legs = {}
-    try:
-        for n in (1, 2):
-            outs = distributed.run_local_cluster(
-                argv, n_processes=n, timeout=900, cwd=root)
-            legs[n] = [json.loads(o.strip().splitlines()[-1]) for o in outs]
-    except Exception as e:
-        # explicit nulls: check_regression skips None-valued guarded keys
-        # with a note (a recorded measurement failure, like scaling_error)
-        row["multihost_scaling_vs_1proc"] = None
-        row["single_process_slots_instances_per_sec"] = None
-        row["multi_process_slots_instances_per_sec"] = None
-        row["multihost_error"] = str(e)[-400:]
+    if not _cpu_child_legs_allowed():
+        row.update({"multihost_scaling_vs_1proc": None,
+                    "single_process_slots_instances_per_sec": None,
+                    "multi_process_slots_instances_per_sec": None})
         return row
+    legs = {}
+    for n in (1, 2):
+        outs = distributed.run_local_cluster(
+            argv, n_processes=n, timeout=900, cwd=root)
+        legs[n] = [json.loads(o.strip().splitlines()[-1]) for o in outs]
     # every worker gathered the full global totals; all must agree with
     # the 1-process leg bit for bit (json round-trips floats exactly)
     ref = legs[1][0]["total"]
@@ -1015,9 +1023,8 @@ def check(rows, cores=None):
         # fleet engine must not cost throughput vs the batched engine (0.9:
         # wall-clock noise margin on a timesliced CPU)
         ok = ok and r["fleet_vs_batched_1dev"] >= 0.9
-        # device scaling needs real cores to show up, and a transient
-        # subprocess failure is recorded in scaling_error (visible in the
-        # row / --json), not turned into an acceptance fail.  Full bar with
+        # device scaling needs real cores to show up (None: the forced-CPU
+        # leg did not run beside an accelerator).  Full bar with
         # a core per forced device; a sanity bar on 2-3 cores (the wide-B
         # workload leaves the 1-device run ~single-threaded, so headroom
         # exists — measured ~1.7x on a 2-core host); nothing on 1 core.
@@ -1076,9 +1083,9 @@ def check(rows, cores=None):
                             for w in r["per_width"]) for r in lf)
     mh = [r for r in rows if r["name"] == "multihost_scaling"]
     # acceptance: the 2-process leg's gathered global totals are
-    # bit-identical to the 1-process leg's (the in-row assert; a cluster
-    # bring-up failure is recorded in multihost_error, not a fail — same
-    # convention as scaling_vs_1dev).  The >1.0 aggregate-throughput bar
+    # bit-identical to the 1-process leg's (the in-row assert; None: the
+    # CPU cluster legs did not run beside an accelerator, or fast mode
+    # skipped them).  The >1.0 aggregate-throughput bar
     # needs a core per process, so it applies only with >= 2 cores.
     ok = ok and len(mh) == 1
     for r in mh:

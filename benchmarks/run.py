@@ -101,6 +101,9 @@ def main() -> None:
     fast = "--fast" in sys.argv
     failures = []
     import jax
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     report = {"schema_version": 1, "fast": fast, "modules": [],
               "throughput": {},
               # JAX process topology of THIS benchmark process (additive,

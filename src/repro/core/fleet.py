@@ -201,7 +201,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.costs import HostingCosts, HostingGrid, default_float_dtype
@@ -221,6 +220,16 @@ from repro.sharding.specs import (FLEET_AXIS, fleet_mesh,
                                   mesh_is_multiprocess,
                                   mesh_local_device_count,
                                   mesh_process_count)
+
+
+def _fleet_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` over the ``fleet`` mesh with the varying-axes
+    check off.  Every core maps P(fleet) rows to P(fleet) rows with no
+    collective, so there is no replicated output to verify; the check
+    would only demand a ``pcast`` on every scan carry the core builds from
+    constants (DP frontiers, accumulators, policy and generator state)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ----------------------------------------------------------------------
@@ -524,8 +533,8 @@ def _vmap_init(init_fn, params, mesh):
     under ``shard_map`` so every state leaf comes out P(fleet)-sharded
     (ready for the compiled step's in_specs with no resharding)."""
     if mesh_is_multiprocess(mesh):
-        f = shard_map(jax.vmap(init_fn), mesh=mesh, in_specs=(P(FLEET_AXIS),),
-                      out_specs=P(FLEET_AXIS), check_rep=False)
+        f = _fleet_map(jax.vmap(init_fn), mesh=mesh, in_specs=(P(FLEET_AXIS),),
+                       out_specs=P(FLEET_AXIS))
         return jax.jit(f)(params)
     return jax.jit(jax.vmap(init_fn))(params)
 
@@ -814,9 +823,9 @@ def _compiled_fleet_core(init_fn, step_fn, include_final_fetch: bool,
     n_args = 7 + int(has_svc) + int(has_side)
     spec = P(FLEET_AXIS)
     n_out = 3 if collect_trace else 2
-    sharded = shard_map(jax.vmap(core), mesh=mesh,
-                        in_specs=(spec,) * n_args,
-                        out_specs=(spec,) * n_out)
+    sharded = _fleet_map(jax.vmap(core), mesh=mesh,
+                         in_specs=(spec,) * n_args,
+                         out_specs=(spec,) * n_out)
     return jax.jit(sharded)
 
 
@@ -870,10 +879,9 @@ def _compiled_scenario_core(init_fn, step_fn, sc_init, sc_chunk,
                                         collect_trace)
     spec = P(FLEET_AXIS)
     n_out = 3 if collect_trace else 2
-    sharded = shard_map(
+    sharded = _fleet_map(
         jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)), mesh=mesh,
-        in_specs=(spec,) * 6 + (P(),), out_specs=(spec,) * n_out,
-        check_rep=False)  # generators may use while-loops (e.g. Poisson)
+        in_specs=(spec,) * 6 + (P(),), out_specs=(spec,) * n_out)
     return jax.jit(sharded)
 
 
@@ -912,8 +920,8 @@ def _compiled_stream_step(init_fn, step_fn, include_final_fetch: bool,
     in_axes = (0, 0, 0, 0, 0, None, 0, 0, 0) + (0,) * n_opt
     spec = P(FLEET_AXIS)
     in_specs = (spec,) * 5 + (P(),) + (spec,) * (3 + n_opt)
-    sharded = shard_map(jax.vmap(step, in_axes=in_axes, out_axes=(0, 0)),
-                        mesh=mesh, in_specs=in_specs, out_specs=(spec, spec))
+    sharded = _fleet_map(jax.vmap(step, in_axes=in_axes, out_axes=(0, 0)),
+                         mesh=mesh, in_specs=in_specs, out_specs=(spec, spec))
     donate_argnums = tuple(range(6, 9 + n_opt)) if donate else ()
     return jax.jit(sharded, donate_argnums=donate_argnums)
 
@@ -943,9 +951,8 @@ def _compiled_scenario_stream_step(init_fn, step_fn, sc_init, sc_chunk,
     in_axes = (0, 0, 0, 0, 0, 0, None, 0)
     in_specs = (spec,) * 6 + (P(),) + (spec,)
     out_specs = (spec, spec) if collect_trace else spec
-    sharded = shard_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
-                        in_specs=in_specs, out_specs=out_specs,
-                        check_rep=False)
+    sharded = _fleet_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
+                         in_specs=in_specs, out_specs=out_specs)
     return jax.jit(sharded, donate_argnums=(7,) if donate else ())
 
 
@@ -1113,11 +1120,9 @@ def _compiled_fanout_core(lane_fns, lane_own, include_final_fetch: bool,
     spec = P(FLEET_AXIS)
     n_args = 4 + int(has_svc) + int(has_side)
     n_out = n_lanes * (2 + int(collect_trace) + int(with_opt))
-    sharded = shard_map(jax.vmap(core), mesh=mesh,
-                        in_specs=(spec,) * n_args,
-                        out_specs=(spec,) * n_out,
-                        # pallas_call has no replication rule
-                        check_rep=(not with_opt) or dp_backend == "xla")
+    sharded = _fleet_map(jax.vmap(core), mesh=mesh,
+                         in_specs=(spec,) * n_args,
+                         out_specs=(spec,) * n_out)
     return jax.jit(sharded)
 
 
@@ -1132,9 +1137,9 @@ def _compiled_fanout_scenario_core(lane_fns, lane_own, sc_init, sc_chunk,
     n_lanes = len(lane_fns)
     spec = P(FLEET_AXIS)
     n_out = n_lanes * (2 + int(collect_trace) + int(with_opt))
-    sharded = shard_map(jax.vmap(core, in_axes=(0, 0, 0, None)), mesh=mesh,
-                        in_specs=(spec, spec, spec, P()),
-                        out_specs=(spec,) * n_out, check_rep=False)
+    sharded = _fleet_map(jax.vmap(core, in_axes=(0, 0, 0, None)), mesh=mesh,
+                         in_specs=(spec, spec, spec, P()),
+                         out_specs=(spec,) * n_out)
     return jax.jit(sharded)
 
 
@@ -1168,9 +1173,8 @@ def _compiled_fanout_stream_step(lane_fns, lane_own,
     spec = P(FLEET_AXIS)
     in_specs = (spec, spec, P(), spec, spec, spec) + (spec,) * n_opt
     out_specs = (spec, spec) if collect_trace else spec
-    sharded = shard_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
-                        in_specs=in_specs, out_specs=out_specs,
-                        check_rep=(not with_opt) or dp_backend == "xla")
+    sharded = _fleet_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
+                         in_specs=in_specs, out_specs=out_specs)
     donate_argnums = tuple(range(3, 6 + n_opt)) if donate else ()
     return jax.jit(sharded, donate_argnums=donate_argnums)
 
@@ -1206,9 +1210,8 @@ def _compiled_fanout_scenario_stream_step(lane_fns, lane_own, sc_init,
     in_axes = (0, 0, 0, None, 0)
     in_specs = (spec, spec, spec, P(), spec)
     out_specs = (spec, spec) if collect_trace else spec
-    sharded = shard_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
-                        in_specs=in_specs, out_specs=out_specs,
-                        check_rep=False)
+    sharded = _fleet_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
+                         in_specs=in_specs, out_specs=out_specs)
     return jax.jit(sharded, donate_argnums=(4,) if donate else ())
 
 
@@ -1639,7 +1642,10 @@ class FleetStepper:
                  fanout=False, n_policies=1, with_opt=False,
                  lane_lv_host=None):
         self._call = call
-        self.carry = carry
+        # the step returns its carry row-sharded over the mesh; commit the
+        # initial carry the same way, or the first step's uncommitted
+        # input would give it a trace of its own
+        self.carry = jax.device_put(carry, NamedSharding(mesh, P(FLEET_AXIS)))
         self.chunk = int(chunk)
         self._mesh = mesh
         self._has_out = has_out
@@ -2258,11 +2264,9 @@ def _compiled_dp_core(n_chunks: int, has_svc: bool, mesh: Mesh,
                       dp_backend: str = "xla"):
     core = _make_dp_instance_core(n_chunks, has_svc, dp_backend)
     spec = P(FLEET_AXIS)
-    sharded = shard_map(jax.vmap(core), mesh=mesh,
-                        in_specs=(spec,) * (7 + int(has_svc)),
-                        out_specs=(spec, spec),
-                        # pallas_call has no replication rule
-                        check_rep=dp_backend == "xla")
+    sharded = _fleet_map(jax.vmap(core), mesh=mesh,
+                         in_specs=(spec,) * (7 + int(has_svc)),
+                         out_specs=(spec, spec))
     return jax.jit(sharded)
 
 
@@ -2271,9 +2275,9 @@ def _compiled_dp_scenario_core(sc_init, sc_chunk, n_chunks: int, mesh: Mesh,
                                dp_backend: str = "xla"):
     core = _make_dp_scenario_core(sc_init, sc_chunk, n_chunks, dp_backend)
     spec = P(FLEET_AXIS)
-    sharded = shard_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
-                        mesh=mesh, in_specs=(spec,) * 6 + (P(),),
-                        out_specs=(spec, spec), check_rep=False)
+    sharded = _fleet_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
+                         mesh=mesh, in_specs=(spec,) * 6 + (P(),),
+                         out_specs=(spec, spec))
     return jax.jit(sharded)
 
 
@@ -2285,10 +2289,9 @@ def _compiled_dp_ckpt_core(n_chunks: int, has_svc: bool,
                                        dp_backend)
     spec = P(FLEET_AXIS)
     out_specs = (spec, spec) if collect_schedule else spec
-    sharded = shard_map(jax.vmap(core), mesh=mesh,
-                        in_specs=(spec,) * (7 + int(has_svc)),
-                        out_specs=out_specs,
-                        check_rep=dp_backend == "xla")
+    sharded = _fleet_map(jax.vmap(core), mesh=mesh,
+                         in_specs=(spec,) * (7 + int(has_svc)),
+                         out_specs=out_specs)
     return jax.jit(sharded)
 
 
@@ -2300,9 +2303,9 @@ def _compiled_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
                                        collect_schedule, dp_backend)
     spec = P(FLEET_AXIS)
     out_specs = (spec, spec) if collect_schedule else spec
-    sharded = shard_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
-                        mesh=mesh, in_specs=(spec,) * 6 + (P(),),
-                        out_specs=out_specs, check_rep=False)
+    sharded = _fleet_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
+                         mesh=mesh, in_specs=(spec,) * 6 + (P(),),
+                         out_specs=out_specs)
     return jax.jit(sharded)
 
 
@@ -2332,9 +2335,8 @@ def _compiled_dp_stream_fwd(has_svc: bool, mesh: Mesh,
     in_axes = (0, 0, 0, 0, 0, None, 0, 0, 0) + (0,) * n_opt
     spec = P(FLEET_AXIS)
     in_specs = (spec,) * 5 + (P(),) + (spec,) * (3 + n_opt)
-    sharded = shard_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
-                        in_specs=in_specs, out_specs=spec,
-                        check_rep=dp_backend == "xla")
+    sharded = _fleet_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
+                         in_specs=in_specs, out_specs=spec)
     donate_argnums = tuple(range(6, 9 + n_opt)) if donate else ()
     return jax.jit(sharded, donate_argnums=donate_argnums)
 
@@ -2359,9 +2361,8 @@ def _compiled_dp_stream_bwd(has_svc: bool, mesh: Mesh,
     in_axes = (0, 0, 0, 0, 0, None, 0, 0, 0, 0) + (0,) * n_opt
     spec = P(FLEET_AXIS)
     in_specs = (spec,) * 5 + (P(),) + (spec,) * (4 + n_opt)
-    sharded = shard_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
-                        in_specs=in_specs, out_specs=(spec, spec),
-                        check_rep=dp_backend == "xla")
+    sharded = _fleet_map(jax.vmap(step, in_axes=in_axes), mesh=mesh,
+                         in_specs=in_specs, out_specs=(spec, spec))
     return jax.jit(sharded)
 
 
@@ -2386,10 +2387,9 @@ def _compiled_dp_scenario_stream_fwd(sc_init, sc_chunk, chunk: int,
         return gen2, J2
 
     spec = P(FLEET_AXIS)
-    sharded = shard_map(
+    sharded = _fleet_map(
         jax.vmap(step, in_axes=(0, 0, 0, 0, 0, 0, None, 0)), mesh=mesh,
-        in_specs=(spec,) * 6 + (P(), spec), out_specs=(spec, spec),
-        check_rep=False)
+        in_specs=(spec,) * 6 + (P(), spec), out_specs=(spec, spec))
     return jax.jit(sharded, donate_argnums=(7,) if donate else ())
 
 
@@ -2411,10 +2411,9 @@ def _compiled_dp_scenario_stream_bwd(sc_init, sc_chunk, chunk: int,
         return k0, rck.astype(jnp.int32)
 
     spec = P(FLEET_AXIS)
-    sharded = shard_map(
+    sharded = _fleet_map(
         jax.vmap(step, in_axes=(0, 0, 0, 0, 0, 0, None, 0, 0, 0)), mesh=mesh,
-        in_specs=(spec,) * 6 + (P(),) + (spec,) * 3, out_specs=(spec, spec),
-        check_rep=False)
+        in_specs=(spec,) * 6 + (P(),) + (spec,) * 3, out_specs=(spec, spec))
     return jax.jit(sharded)
 
 
@@ -2735,9 +2734,9 @@ def _make_schedule_scenario_core(sc_init, sc_chunk, n_chunks: int):
 def _compiled_schedule_core(n_chunks: int, has_svc: bool, mesh: Mesh):
     core = _make_schedule_instance_core(n_chunks, has_svc)
     spec = P(FLEET_AXIS)
-    sharded = shard_map(jax.vmap(core), mesh=mesh,
-                        in_specs=(spec,) * (7 + int(has_svc)),
-                        out_specs=(spec, spec))
+    sharded = _fleet_map(jax.vmap(core), mesh=mesh,
+                         in_specs=(spec,) * (7 + int(has_svc)),
+                         out_specs=(spec, spec))
     return jax.jit(sharded)
 
 
@@ -2746,9 +2745,9 @@ def _compiled_schedule_scenario_core(sc_init, sc_chunk, n_chunks: int,
                                      mesh: Mesh):
     core = _make_schedule_scenario_core(sc_init, sc_chunk, n_chunks)
     spec = P(FLEET_AXIS)
-    sharded = shard_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
-                        mesh=mesh, in_specs=(spec,) * 6 + (P(),),
-                        out_specs=(spec, spec), check_rep=False)
+    sharded = _fleet_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
+                         mesh=mesh, in_specs=(spec,) * 6 + (P(),),
+                         out_specs=(spec, spec))
     return jax.jit(sharded)
 
 

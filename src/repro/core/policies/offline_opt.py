@@ -140,8 +140,10 @@ def dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
     if backend == "pallas":
         # lazy import: the kernels package (and Pallas) loads only when a
         # non-default backend is actually requested
+        # every driver hands in contiguous counters t0 + arange(chunk), so
+        # the live slots are the first T_len - t0
         from repro.kernels.hosting import dp_minplus_kc
-        return dp_minplus_kc(J, wck, fetch_mat, tids < T_len)
+        return dp_minplus_kc(J, wck, fetch_mat, T_len - tids[0])
     if backend != "xla":
         raise ValueError(f"backend must be one of {DP_BACKENDS}, "
                          f"got {backend!r}")

@@ -7,16 +7,13 @@ shared padding/block plumbing in ``utils.py``.
 
 The ``interpret=True``-on-CPU convention
 ----------------------------------------
-Every wrapper takes an ``interpret`` flag.  On CPU (the test/CI platform)
-there is no Mosaic backend, so ``interpret=True`` is the only executable
-path: the kernel body runs through the Pallas interpreter as plain XLA
-ops — semantically (and for the hosting kernels *bitwise*) identical to
-the compiled lowering, but NOT a TPU performance proxy.  Wrappers called
-from the engine resolve ``interpret=None`` via ``utils.default_interpret``
-(True iff ``jax.default_backend() == "cpu"``); benchmarks record which leg
-they measured (``backend`` / ``device_kind`` keys in ``benchmarks/run.py
---json``).  On real TPU pass ``interpret=False`` (or rely on the default
-resolution) to get the compiled kernel.
+Every wrapper takes an ``interpret`` flag, default ``None``, which
+``utils.default_interpret`` resolves to True iff ``jax.default_backend()
+== "cpu"``.  On CPU (the test/CI platform) there is no Mosaic backend, so
+the kernel body runs through the Pallas interpreter as plain XLA ops —
+semantically (and for the hosting kernels *bitwise*) identical to the
+compiled lowering, but NOT a TPU performance proxy.  On TPU the same call
+runs the compiled kernel.
 
 Backend-dispatch rules (pure XLA stays canonical; any alternative kernel
 must prove exact bit-identity before it can be selected) are documented in

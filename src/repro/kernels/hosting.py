@@ -37,13 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.utils import default_interpret, pad_to
-
-# Slot axis is padded to this multiple (f32 sublane count on TPU); padded
-# DP slots run as frozen (valid=False) slots, padded PRNG counters draw
-# dead uniforms — both sliced off by the wrappers.
-_SLOT_MULT = 8
+from repro.kernels.utils import default_interpret
 
 
 # ----------------------------------------------------------------------
@@ -93,71 +89,86 @@ def uniform_from_bits(bits):
 # Kernel 1: fused DP min-plus forward chunk.
 # ----------------------------------------------------------------------
 
-def _dp_minplus_kernel(j_ref, w_ref, f_ref, valid_ref, jout_ref, args_ref,
-                       *, chunk: int, K: int):
+def _dp_minplus_kernel(n_live_ref, j_ref, w_ref, f_ref, jout_ref, args_ref,
+                       w_s, args_s, *, chunk: int, K: int):
     fetch = f_ref[...]                            # [K, K], VMEM-resident
-    iota = jax.lax.iota(jnp.int32, K)
+    n_live = n_live_ref[0, 0]                     # SMEM scalar
+    rows = jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+    w_s[...] = jnp.transpose(w_ref[...])          # [K, chunk] -> [chunk, K]
 
-    def body(t, J):
-        # the exact op order of dp_fwd_chunk's scan body — argmin before
-        # min matters for nothing, but where/add order does for bits
-        trans = J[:, None] + fetch                # [K_prev, K_next]
-        arg = jnp.argmin(trans, axis=0)
-        Jn = jnp.min(trans, axis=0) + w_ref[t, :]
-        v = valid_ref[t]
-        Jn = jnp.where(v, Jn, J)
-        arg = jnp.where(v, arg, iota)
-        args_ref[t, :] = arg
-        return Jn
+    def body(t, J):                               # J [1, K] frontier row
+        # the exact op order of dp_fwd_chunk's scan body — where/add order
+        # matters for bits; the argmin is spelled out as "lowest k_prev
+        # holding the min", jnp.argmin's first-occurrence tie rule
+        trans = jnp.transpose(J) + fetch          # [K_prev, K_next]
+        m = jnp.min(trans, axis=0, keepdims=True)
+        arg = jnp.min(jnp.where(trans == m, rows, K), axis=0, keepdims=True)
+        Jn = m + w_s[pl.ds(t, 1), :]
+        v = jnp.full((1, K), t, jnp.int32) < n_live
+        args_s[pl.ds(t, 1), :] = jnp.where(v, arg, iota)
+        return jnp.where(v, Jn, J)
 
     jout_ref[...] = jax.lax.fori_loop(0, chunk, body, j_ref[...])
+    args_ref[...] = jnp.transpose(args_s[...])
 
 
-def dp_minplus_kc(J, wck, fetch_mat, valid, *, interpret=None):
+def dp_minplus_kc(J, wck, fetch_mat, n_live, *, interpret=None):
     """One instance, one chunk of the DP forward recursion.
 
     Args: ``J`` [K] float32 entry frontier; ``wck`` [chunk, K] float32
     per-slot holding costs (``+inf`` on masked levels); ``fetch_mat``
-    [K, K] float32; ``valid`` [chunk] bool (``tids < T_len``).
+    [K, K] float32; ``n_live`` int32 scalar — slots ``t < n_live`` are
+    live, the rest frozen (``T_len - t0`` for the chunk's contiguous
+    counters ``t0 + arange(chunk)``).
     Returns ``(J' [K], args [chunk, K] int32)`` — bit-identical to the
     ``lax.scan`` body in ``offline_opt.dp_fwd_chunk``.
 
     Batched use is ``jax.vmap`` over a leading [B] axis (Pallas turns that
-    into the batch grid dimension).  The slot axis is padded to a sublane
-    multiple with *frozen* slots (valid=False carries J through and writes
-    identity argmins), so padding is exact by the same invariant that
-    freezes real slots past ``T_len``.
+    into the batch grid dimension).  Every per-instance operand is at
+    least 2-D with its trailing dims whole (``J`` as a ``[1, K]`` row,
+    ``n_live`` a ``[1, 1]`` SMEM scalar), which keeps the batched blocks
+    legal for Mosaic.  The two tables cross HBM slot-minor (``[K, chunk]``),
+    so K pads to 8 sublanes rather than 128 lanes — at B = chunk = 4096 the
+    slot-major form would need 8.6 GB per table.  The kernel transposes
+    them to and from ``[chunk, K]`` VMEM scratch once per chunk, which
+    bounds ``chunk`` by VMEM (about 1 KiB per slot).
     """
     chunk, K = wck.shape
     if interpret is None:
         interpret = default_interpret()
-    wck, _ = pad_to(wck, 0, _SLOT_MULT)
-    valid, _ = pad_to(valid, 0, _SLOT_MULT)       # pads False -> frozen
-    chunk_p = wck.shape[0]
-    kernel = functools.partial(_dp_minplus_kernel, chunk=chunk_p, K=K)
-    Jout, args = pl.pallas_call(
+    kernel = functools.partial(_dp_minplus_kernel, chunk=chunk, K=K)
+    vmem = pl.BlockSpec()
+    Jout, args_t = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((K,), jnp.float32),
-                   jax.ShapeDtypeStruct((chunk_p, K), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((1, K), jnp.float32),
+                   jax.ShapeDtypeStruct((K, chunk), jnp.int32)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vmem, vmem, vmem],
+        scratch_shapes=[pltpu.VMEM((chunk, K), jnp.float32),
+                        pltpu.VMEM((chunk, K), jnp.int32)],
         interpret=interpret,
-    )(J.astype(jnp.float32), wck.astype(jnp.float32),
-      fetch_mat.astype(jnp.float32), valid)
-    return Jout, args[:chunk]
+    )(jnp.asarray(n_live, jnp.int32).reshape(1, 1),
+      J.astype(jnp.float32).reshape(1, K), wck.astype(jnp.float32).T,
+      fetch_mat.astype(jnp.float32))
+    return Jout[0], args_t.T
 
 
 # ----------------------------------------------------------------------
 # Kernel 2: fused counter-keyed uniform generation.
 # ----------------------------------------------------------------------
 
-def _slot_uniform_kernel(key_ref, t_ref, u_ref, *, salt):
-    k0 = key_ref[0]
-    k1 = key_ref[1]
+def _slot_uniform_kernel(key_ref, t_ref, u_ref, *, salt, partitionable):
+    k0 = key_ref[0, 0]                            # SMEM scalars
+    k1 = key_ref[0, 1]
     t = t_ref[...].astype(jnp.uint32)
     z = jnp.zeros_like(t)
     a0, a1 = threefry2x32(k0, k1, z, t)           # fold_in(key, t)
     if salt is not None:
         a0, a1 = threefry2x32(a0, a1, z, jnp.full_like(t, np.uint32(salt)))
-    bits, _ = threefry2x32(a0, a1, z, z)          # random_bits(key, 32, ())
+    b0, b1 = threefry2x32(a0, a1, z, z)           # random_bits(key, 32, ())
+    # jax's two random_bits layouts: the partitionable one (the default
+    # since jax 0.5) xors both output words, the original keeps word 0
+    bits = b0 ^ b1 if partitionable else b0
     u_ref[...] = uniform_from_bits(bits)
 
 
@@ -168,17 +179,22 @@ def slot_uniform_tc(key, tids, salt=None, *, interpret=None):
     slot counters; ``salt`` optional *static* int sub-stream fold.
     Returns [chunk] float32 — bit-identical to
     ``scenarios.base.slot_uniform``'s vmapped ``fold_in`` + ``uniform``
-    chain.  Batched use is ``jax.vmap`` over [B, 2] keys.
+    chain.  Batched use is ``jax.vmap`` over [B, 2] keys (and optionally
+    [B, chunk] counters): the key rides as a ``[1, 2]`` SMEM operand and
+    the counters as one ``[1, chunk]`` row, so the batched blocks keep
+    whole trailing dims.
     """
     chunk = tids.shape[0]
     if interpret is None:
         interpret = default_interpret()
-    tids, _ = pad_to(tids, 0, _SLOT_MULT)         # dead counters, sliced off
-    kernel = functools.partial(_slot_uniform_kernel,
-                               salt=None if salt is None else int(salt))
+    kernel = functools.partial(
+        _slot_uniform_kernel, salt=None if salt is None else int(salt),
+        partitionable=bool(jax.config.jax_threefry_partitionable))
     u = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(tids.shape, jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, chunk), jnp.float32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), pl.BlockSpec()],
         interpret=interpret,
-    )(jnp.asarray(key, jnp.uint32), tids)
-    return u[:chunk]
+    )(jnp.asarray(key, jnp.uint32).reshape(1, 2),
+      jnp.asarray(tids, jnp.int32).reshape(1, chunk))
+    return u[0]

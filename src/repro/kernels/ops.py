@@ -1,6 +1,7 @@
 """Jitted public wrappers around the Pallas kernels: layout handling,
-padding to block multiples, and dtype plumbing.  ``interpret`` defaults to
-True (CPU validation); on real TPU pass interpret=False.
+padding to block multiples, and dtype plumbing.  ``interpret=None`` (the
+default) resolves through ``kernels.utils.default_interpret``: the Pallas
+interpreter on CPU, the compiled Mosaic kernel on TPU.
 
 Padding/block-fitting arithmetic lives in ``kernels.utils`` (one shared
 copy, also used by the hosting kernels' own wrappers).
@@ -16,14 +17,16 @@ import numpy as np
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.hosting import dp_minplus_kc, slot_uniform_tc
 from repro.kernels.ssd_scan import ssd_scan_bhcqd
-from repro.kernels.utils import fit_block, pad_to as _pad_to
+from repro.kernels.utils import default_interpret, fit_block, pad_to as _pad_to
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "q_offset", "bq", "bk",
                                              "interpret"))
 def flash_attention(q, k, v, causal: bool = True, q_offset: int = 0,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128, interpret=None):
     """q [B,S,Hq,hd]; k/v [B,Skv,Hkv,hd] -> [B,S,Hq,hd]."""
+    if interpret is None:
+        interpret = default_interpret()
     b, sq, hq, hd = q.shape
     qb = jnp.moveaxis(q, 2, 1)                    # [B,H,S,hd]
     kb = jnp.moveaxis(k, 2, 1)
@@ -40,9 +43,11 @@ def flash_attention(q, k, v, causal: bool = True, q_offset: int = 0,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, h0=None, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, h0=None, chunk: int = 128, interpret=None):
     """Mamba2 SSD. x [b,s,nh,dh]; dt [b,s,nh]; A [nh]; B/C [b,s,ng,ds];
     h0 [b,nh,dh,ds] or None.  Returns (y [b,s,nh,dh], hT)."""
+    if interpret is None:
+        interpret = default_interpret()
     b, s, nh, dh = x.shape
     ng, ds = B.shape[2], B.shape[3]
     q = fit_block(chunk, s)
@@ -64,21 +69,22 @@ def ssd_scan(x, dt, A, B, C, h0=None, chunk: int = 128, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dp_minplus(J, wck, fetch_mat, valid, interpret: bool = True):
+def dp_minplus(J, wck, fetch_mat, n_live, interpret=None):
     """Fused DP min-plus forward chunk (``hosting.dp_minplus_kc``).
 
-    Per-instance: J [K], wck [chunk, K], fetch_mat [K, K], valid [chunk];
-    batched: a leading [B] axis on every arg.  Returns ``(J', args)`` —
-    bit-identical to ``offline_opt.dp_fwd_chunk``'s scan.
+    Per-instance: J [K], wck [chunk, K], fetch_mat [K, K], n_live int32
+    scalar (slots ``t < n_live`` are live); batched: a leading [B] axis on
+    every arg.  Returns ``(J', args)`` — bit-identical to
+    ``offline_opt.dp_fwd_chunk``'s scan.
     """
     if J.ndim == 2:
-        return jax.vmap(lambda j, w, f, v: dp_minplus_kc(
-            j, w, f, v, interpret=interpret))(J, wck, fetch_mat, valid)
-    return dp_minplus_kc(J, wck, fetch_mat, valid, interpret=interpret)
+        return jax.vmap(lambda j, w, f, n: dp_minplus_kc(
+            j, w, f, n, interpret=interpret))(J, wck, fetch_mat, n_live)
+    return dp_minplus_kc(J, wck, fetch_mat, n_live, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("salt", "interpret"))
-def counter_uniforms(keys, tids, salt=None, interpret: bool = True):
+def counter_uniforms(keys, tids, salt=None, interpret=None):
     """Fused counter-keyed uniforms (``hosting.slot_uniform_tc``).
 
     ``keys`` raw uint32 [2] (one instance) or [B, 2]; ``tids`` [chunk]

@@ -1,10 +1,10 @@
 """Shared wrapper plumbing for the Pallas kernels: padding to block
 multiples, block-size fitting, and the interpret-default resolution.
 
-Every public wrapper in ``ops.py`` (and the hosting kernels'
-``dp_minplus_kc`` / ``slot_uniform_tc``) pads its inputs up to the kernel's
-block multiple, runs the kernel, and slices the pad back off — this module
-is the ONE copy of that arithmetic.
+The model-kernel wrappers in ``ops.py`` pad their inputs up to the
+kernel's block multiple, run the kernel, and slice the pad back off — this
+module is the ONE copy of that arithmetic.  (The hosting kernels take
+whole per-instance blocks and need no padding.)
 """
 from __future__ import annotations
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -81,11 +80,11 @@ def flash_decode(q, k, v, pos, mesh: Mesh, axis: str = "model",
         return out.reshape(qb.shape).astype(qb.dtype)
 
     bs = batch_spec
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(bs, None, None, None), P(bs, axis, None, None),
-                             P(bs, axis, None, None)),
-                   out_specs=P(bs, None, None, None),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(bs, None, None, None), P(bs, axis, None, None),
+                                 P(bs, axis, None, None)),
+                       out_specs=P(bs, None, None, None),
+                       check_vma=False)
     return fn(q, k, v)
 
 
@@ -114,11 +113,11 @@ def flash_decode_update(q, k_new, v_new, k_cache, v_cache, pos, mesh: Mesh,
         return out, kcb, vcb
 
     bs = batch_spec
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(bs, None, None, None), P(bs, None, None, None),
-                             P(bs, None, None, None), P(bs, axis, None, None),
-                             P(bs, axis, None, None)),
-                   out_specs=(P(bs, None, None, None), P(bs, axis, None, None),
-                              P(bs, axis, None, None)),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(bs, None, None, None), P(bs, None, None, None),
+                                 P(bs, None, None, None), P(bs, axis, None, None),
+                                 P(bs, axis, None, None)),
+                       out_specs=(P(bs, None, None, None), P(bs, axis, None, None),
+                                  P(bs, axis, None, None)),
+                       check_vma=False)
     return fn(q, k_new, v_new, k_cache, v_cache)

@@ -69,10 +69,7 @@ def initialize(coordinator_address: Optional[str] = None,
     import jax
     # CPU collectives must be picked before the backend initializes; gloo
     # is what makes cross-process psum/allgather work on CPU-only hosts.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
-    except Exception:
-        pass  # option absent on this jax version; distributed may still work
+    jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.sharding.context import ShardCtx
 
@@ -97,13 +96,13 @@ def moe_shardmap_apply(ctx: ShardCtx, x, w, ids, w_gate, w_in, w_out,
     mask_arg = expert_mask if expert_mask is not None else jnp.ones((e_total,), jnp.float32)
     mask_spec = P(ma)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=(P(bspec, None, None), P(bspec, None, None), P(bspec, None, None),
                   P(ma, None, None), P(ma, None, None), P(ma, None, None),
                   mask_spec),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w, ids, w_gate, w_in, w_out,
               mask_arg if expert_mask is not None else mask_arg)

@@ -13,8 +13,8 @@ backend-dispatch invariant (ROADMAP.md):
   stream whose innovations AND emissions both ride ``slot_uniform``);
 * argument validation (unknown backends; prng reroute without a scenario);
 * a forced-4-CPU-device subprocess leg proving the pallas legs shard (the
-  compiled cores drop ``check_rep`` — pallas_call has no replication
-  rule — so the mesh path needs its own proof).
+  mesh path runs the kernels under ``shard_map`` and needs its own
+  proof).
 """
 import os
 import subprocess
@@ -191,8 +191,7 @@ def test_backend_config_walk(kw, kind, mode):
 
 # ----------------------------------------------------------------------
 # Forced-multi-device leg (subprocess; conftest pins this process to one
-# device).  The pallas cores run with check_rep=False, so sharded == XLA
-# needs an explicit proof.
+# device): sharded pallas == XLA needs an explicit proof.
 # ----------------------------------------------------------------------
 
 _SUBPROCESS_SCRIPT = textwrap.dedent("""

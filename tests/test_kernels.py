@@ -269,15 +269,15 @@ def test_dp_minplus_batched_wrapper():
              for s, (k, t) in enumerate([(5, 20), (3, 7), (2, 0)])]
     J = jnp.stack([c[0] for c in cases])
     w = []
-    valid = []
+    n_live = []
     for c in cases:
         _, tids, cck, sck, lv32, kmask, _, T_len = c
         wck = cck[:, None] * lv32[None, :] + sck
         w.append(jnp.where(kmask[None, :], wck, jnp.inf))
-        valid.append(tids < T_len)
+        n_live.append(T_len - tids[0])
     Jb, ab = ops.dp_minplus(J, jnp.stack(w),
                             jnp.stack([c[6] for c in cases]),
-                            jnp.stack(valid))
+                            jnp.stack(n_live))
     for i, c in enumerate(cases):
         Jx, ax = dp_fwd_chunk(*c, "xla")
         assert np.array_equal(np.asarray(Jb[i]), np.asarray(Jx)), i
@@ -363,7 +363,7 @@ def test_hosting_kernels_compiled_mode():
     J0, tids, cck, sck, lv32, kmask, fetch, T_len = case
     wck = jnp.where(kmask[None, :],
                     cck[:, None] * lv32[None, :] + sck, jnp.inf)
-    Jp, ap = dp_minplus_kc(J0, wck, fetch, tids < T_len, interpret=False)
+    Jp, ap = dp_minplus_kc(J0, wck, fetch, T_len - tids[0], interpret=False)
     assert np.array_equal(np.asarray(Jx), np.asarray(Jp))
     assert np.array_equal(np.asarray(ax), np.asarray(ap))
     key = jax.random.PRNGKey(9)
