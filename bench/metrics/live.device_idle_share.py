@@ -1,0 +1,8 @@
+"""1 - device-busy / traced window, averaged over the chips."""
+
+
+def read(run):
+    v = run.view
+    if v is None or not v.spans_named("bench.admit"):
+        return None
+    return v.idle_share()
