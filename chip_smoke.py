@@ -27,9 +27,9 @@ from ``--seed``.  Phases (one chip):
 against one chip, at one B that four divides and one it does not (the
 padding path); results must be bitwise equal.
 
-Each phase prints one JSON line with its checks, ``setup_s`` (the first
-call of each engine entry point: compile included) and ``wall_s`` (the
-same calls again, warm).  Neither is a benchmark.  The last line is
+Each phase prints one JSON line with its checks and ``setup_s`` (the
+first call of each engine entry point: compile included), which is not a
+benchmark.  The last line is
 ``{"ok": true, "device": {...}}`` only if every phase passed; a run that
 finds no TPU exits non-zero before any phase.  The compile cache is
 ``repro.compile_cache`` (``JAX_COMPILATION_CACHE_DIR`` or
@@ -110,15 +110,15 @@ def timed(fn):
 
 
 def first_and_warm(fn):
-    """Call ``fn`` twice: ``(result, first_s, warm_s)``; the two results
-    must be equal (the same program on the same inputs)."""
+    """Call ``fn`` twice: ``(result, first_s)``; the two results must be
+    equal (the same program on the same inputs)."""
     a, first = timed(fn)
-    b, warm = timed(fn)
+    b = fn()
     same = jax.tree_util.tree_map(
         lambda u, v: np.array_equal(np.asarray(u), np.asarray(v)), a, b)
     if not all(jax.tree_util.tree_leaves(same)):
         raise AssertionError("a repeated call returned different results")
-    return a, first, warm
+    return a, first
 
 
 def _sim_arrays(res, *extra):
@@ -153,14 +153,12 @@ def live_phase(B: int, n_slots: int, seed: int, n_ref: int,
     sched = LiveFleetScheduler(costs)
     r = np.empty((B, n_slots), np.int64)
     r[:, 0], setup_s = timed(lambda: sched.admit(x[:, 0], c[:, 0]))
-    t0 = time.perf_counter()
     for t in range(1, n_slots):
         r[:, t] = sched.admit(x[:, t], c[:, t])
-    wall_s = time.perf_counter() - t0
     live = sched.report()
 
     fleet = FleetBatch.from_dense(sched.fleet.grid, x, c)
-    ref, ref_first, ref_warm = first_and_warm(lambda: _sim_arrays(
+    ref, ref_first = first_and_warm(lambda: _sim_arrays(
         run_fleet(AlphaRR.fleet(fleet), fleet), "r_hist"))
     _assert_equal("live decisions vs run_fleet", r, ref.pop("r_hist"))
     for k, v in ref.items():
@@ -184,13 +182,14 @@ def live_phase(B: int, n_slots: int, seed: int, n_ref: int,
             "costs_vs_run_fleet": "bitwise",
             "decisions_vs_run_policy_cpu": "equal",
             "costs_vs_run_policy_cpu_max_rel_err": worst,
-            "setup_s": setup_s + ref_first, "wall_s": wall_s + ref_warm}
+            "setup_s": setup_s + ref_first}
 
 
 def _offline_legs(B, T, chunk, seed, *, mesh=None, backend="xla",
                   collect_schedule=False):
-    """The offline pricing calls of one leg: ``(online, opt, setup_s,
-    wall_s)`` — both entry points called twice (first, warm)."""
+    """The offline pricing calls of one leg: ``(costs, scenario, online,
+    opt, setup_s)`` — both entry points called twice, the first call
+    timed."""
     from repro.core.costs import HostingGrid
     from repro.core.fleet import FleetBatch, offline_opt_fleet, run_fleet
     from repro.core.policies import AlphaRR, RetroRenting
@@ -198,7 +197,7 @@ def _offline_legs(B, T, chunk, seed, *, mesh=None, backend="xla",
     costs, sc = workload(B, seed)
     fleet = FleetBatch.for_scenario(HostingGrid.from_costs(costs), T)
     lanes = [AlphaRR.fleet_lane(fleet), RetroRenting.fleet_lane(fleet)]
-    online, on_first, on_warm = first_and_warm(lambda: _sim_arrays(run_fleet(
+    online, on_first = first_and_warm(lambda: _sim_arrays(run_fleet(
         lanes, fleet, scenario=sc, mesh=mesh, chunk_size=chunk,
         collect_trace=False, prng_backend=backend)))
 
@@ -211,8 +210,8 @@ def _offline_legs(B, T, chunk, seed, *, mesh=None, backend="xla",
             return {"cost": res.cost}
         return dict(cost=res.cost, r_hist=res.r_hist, **_sim_arrays(res.sim))
 
-    opt, op_first, op_warm = first_and_warm(price_opt)
-    return costs, sc, online, opt, on_first + op_first, on_warm + op_warm
+    opt, op_first = first_and_warm(price_opt)
+    return costs, sc, online, opt, on_first + op_first
 
 
 def offline_phase(B: int, T: int, chunk: int, seed: int, n_ref: int,
@@ -220,8 +219,7 @@ def offline_phase(B: int, T: int, chunk: int, seed: int, n_ref: int,
     from repro.core import scenarios as S
     from repro.core.policies.offline_opt import offline_opt
 
-    costs, sc, online, opt, setup_s, wall_s = _offline_legs(B, T, chunk,
-                                                            seed)
+    costs, sc, online, opt, setup_s = _offline_legs(B, T, chunk, seed)
     lane_total = online["total"].reshape(2, B)        # policy-major
     ratio = lane_total / opt["cost"][None, :]
     if not np.all(lane_total >= opt["cost"][None, :] * (1 - OPT_RTOL)):
@@ -243,7 +241,7 @@ def offline_phase(B: int, T: int, chunk: int, seed: int, n_ref: int,
             "mean_lane_over_opt": [float(v) for v in ratio.mean(axis=1)],
             "ref_rows": [int(i) for i in rows],
             "opt_vs_offline_opt_cpu_max_rel_err": worst,
-            "setup_s": setup_s, "wall_s": wall_s}
+            "setup_s": setup_s}
 
 
 def _kernels_compiled(B: int, chunk: int, K: int) -> bool:
@@ -311,9 +309,9 @@ def pallas_phase(B: int, T: int, chunk: int, seed: int) -> dict:
     if default_interpret() or not _kernels_compiled(B, chunk, 3):
         raise AssertionError("the Pallas kernels did not compile to Mosaic")
     odd = _kernels_at_odd_shapes(seed)
-    _, _, on_x, opt_x, setup_x, wall_x = _offline_legs(
+    _, _, on_x, opt_x, setup_x = _offline_legs(
         B, T, chunk, seed, collect_schedule=True)
-    _, _, on_p, opt_p, setup_p, wall_p = _offline_legs(
+    _, _, on_p, opt_p, setup_p = _offline_legs(
         B, T, chunk, seed, backend="pallas", collect_schedule=True)
     for k in on_x:
         _assert_equal(f"pallas run_fleet {k}", on_p[k], on_x[k])
@@ -323,7 +321,7 @@ def pallas_phase(B: int, T: int, chunk: int, seed: int) -> dict:
             "kernel_shapes_bitwise": odd,
             "pallas_vs_xla": "bitwise (lane costs, OPT cost, schedule, "
                              "schedule costs)",
-            "setup_s": setup_x + setup_p, "wall_s": wall_x + wall_p}
+            "setup_s": setup_x + setup_p}
 
 
 def mesh_phase(B: int, T: int, chunk: int, seed: int, n_dev: int) -> dict:
@@ -332,9 +330,9 @@ def mesh_phase(B: int, T: int, chunk: int, seed: int, n_dev: int) -> dict:
     devs = jax.devices()
     if len(devs) < n_dev:
         raise AssertionError(f"{n_dev} devices needed, {len(devs)} found")
-    _, _, on_1, opt_1, setup_1, wall_1 = _offline_legs(
+    _, _, on_1, opt_1, setup_1 = _offline_legs(
         B, T, chunk, seed, mesh=fleet_mesh(devs[:1]))
-    _, _, on_n, opt_n, setup_n, wall_n = _offline_legs(
+    _, _, on_n, opt_n, setup_n = _offline_legs(
         B, T, chunk, seed, mesh=fleet_mesh(devs[:n_dev]))
     for k in on_1:
         _assert_equal(f"{n_dev}-device run_fleet {k}", on_n[k], on_1[k])
@@ -342,7 +340,7 @@ def mesh_phase(B: int, T: int, chunk: int, seed: int, n_dev: int) -> dict:
     return {"B": B, "T": T, "chunk": chunk, "devices": n_dev,
             "padded_rows": (-B) % n_dev,
             "mesh_vs_one_device": "bitwise (lane costs, OPT cost)",
-            "setup_s": setup_1 + setup_n, "wall_s": wall_1 + wall_n}
+            "setup_s": setup_1 + setup_n}
 
 
 def main(argv=None) -> int:

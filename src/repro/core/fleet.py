@@ -188,6 +188,17 @@ Conventions:
     onto every process (one cross-host collective per array, the only
     cross-host traffic in the engine); it is a no-op on single-process
     meshes, so library code can pass it through unconditionally.
+
+**Names in a profiler trace** — host spans (``jax.profiler``
+annotations, free when no trace is taken) mark the host's part of each
+call: ``repro.step.put`` / ``.dispatch`` / ``.readback`` in
+``FleetStepper`` (each with the stepped ``slot``) and
+``repro.fleet.dispatch`` / ``.readback`` around the whole-horizon cores of
+``run_fleet`` and ``offline_opt_fleet``.  On the device, ``jax.named_scope``
+puts ``gen_arrivals`` / ``gen_rents`` (the combined scenario
+``chunk_fn``), ``policy_step`` (``sim_chunk_core``) and ``dp_fwd``
+(``dp_fwd_chunk``) in each op's ``op_name`` metadata; scopes change no op
+(tests/test_trace_names.py).
 """
 from __future__ import annotations
 
@@ -220,6 +231,22 @@ from repro.sharding.specs import (FLEET_AXIS, fleet_mesh,
                                   mesh_is_multiprocess,
                                   mesh_local_device_count,
                                   mesh_process_count)
+
+
+def _span(name: str, **args):
+    """A host span ``repro.<name>`` in the profiler's trace, on the same
+    clock as the device's ops (about a microsecond when no trace is being
+    taken)."""
+    return jax.profiler.TraceAnnotation("repro." + name, **args)
+
+
+def _dispatch(mesh, core, *args):
+    """Call a compiled whole-horizon core under the fleet's sharding
+    context.  It returns once the program is enqueued; the readback of its
+    results waits for the device."""
+    with _span("fleet.dispatch"), shard_ctx(mesh, (FLEET_AXIS,),
+                                            model_axis=None):
+        return core(*args)
 
 
 def _fleet_map(f, *, mesh, in_specs, out_specs):
@@ -1465,11 +1492,11 @@ def run_fleet(policy, fleet: FleetBatch, *,
                                        include_final_fetch, n_chunks,
                                        collect_trace, mesh)
         tids_all = _dev_replicated(mesh, np.arange(T_pad, dtype=np.int32))
-        with shard_ctx(mesh, (FLEET_AXIS,), model_axis=None):
-            out = core(params, sparams, lv, g, M,
-                       _dev_rows(mesh, padded.T), tids_all)
+        out = _dispatch(mesh, core, params, sparams, lv, g, M,
+                        _dev_rows(mesh, padded.T), tids_all)
         r_hist, sums, counts = out if collect_trace else (None,) + out
-        res = _fleet_result(r_hist, sums, counts, B, T_max, fleet.T, S)
+        with _span("fleet.readback"):
+            res = _fleet_result(r_hist, sums, counts, B, T_max, fleet.T, S)
         return _gather_result(res, mesh) if gather else res
 
     has_svc, has_side = fleet.svc is not None, fleet.side is not None
@@ -1489,10 +1516,10 @@ def run_fleet(policy, fleet: FleetBatch, *,
         args += (_dev_rows(mesh, padded.svc),)
     if has_side:
         args += (_dev_rows(mesh, padded.side),)
-    with shard_ctx(mesh, (FLEET_AXIS,), model_axis=None):
-        out = core(*args)
+    out = _dispatch(mesh, core, *args)
     r_hist, sums, counts = out if collect_trace else (None,) + out
-    res = _fleet_result(r_hist, sums, counts, B, T_max, fleet.T)
+    with _span("fleet.readback"):
+        res = _fleet_result(r_hist, sums, counts, B, T_max, fleet.T)
     return _gather_result(res, mesh) if gather else res
 
 
@@ -1535,9 +1562,8 @@ def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
             include_final_fetch, n_chunks, collect_trace, with_opt,
             dp_backend, mesh)
         tids_all = _dev_replicated(mesh, np.arange(T_pad, dtype=np.int32))
-        with shard_ctx(mesh, (FLEET_AXIS,), model_axis=None):
-            outs = core(lane_args, sparams, _dev_rows(mesh, padded.T),
-                        tids_all)
+        outs = _dispatch(mesh, core, lane_args, sparams,
+                         _dev_rows(mesh, padded.T), tids_all)
     else:
         has_side = padded.side is not None
         if stream:
@@ -1555,8 +1581,7 @@ def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
             args += (_dev_rows(mesh, padded.svc),)
         if has_side:
             args += (_dev_rows(mesh, padded.side),)
-        with shard_ctx(mesh, (FLEET_AXIS,), model_axis=None):
-            outs = core(*args)
+        outs = _dispatch(mesh, core, *args)
     i = 0
     r_lanes = None
     if collect_trace:
@@ -1564,8 +1589,9 @@ def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
     sums_lanes = outs[i:i + n_lanes]
     counts_lanes = outs[i + n_lanes:i + 2 * n_lanes]
     opt_lanes = outs[i + 2 * n_lanes:] if with_opt else None
-    return _fanout_result(r_lanes, sums_lanes, counts_lanes, opt_lanes,
-                          B, T_max, fleet.T, S, mesh, gather)
+    with _span("fleet.readback"):
+        return _fanout_result(r_lanes, sums_lanes, counts_lanes, opt_lanes,
+                              B, T_max, fleet.T, S, mesh, gather)
 
 
 def _run_fleet_fanout_streamed(lanes, lane_fns, lane_own, lane_args,
@@ -1672,7 +1698,8 @@ class FleetStepper:
         # an uncommitted host scalar: valid as a replicated (P()) input on
         # both single- and multi-process meshes, identical trace either way
         t0 = np.int32(self.t)
-        with shard_ctx(self._mesh, (FLEET_AXIS,), model_axis=None):
+        with _span("step.dispatch", slot=self.t), \
+                shard_ctx(self._mesh, (FLEET_AXIS,), model_axis=None):
             out = self._call(self.carry, t0, tuple(slabs))
         if self._has_out:
             self.carry, y = out
@@ -1705,6 +1732,7 @@ class FleetStepper:
         """
         if self._kind != "sim":
             raise ValueError("step() is for simulation steppers")
+        slot = self.t                      # names this step's host spans
         if self._scenario_mode:
             if any(a is not None for a in (x, c, svc, side)):
                 raise ValueError("scenario-fused stepper generates its own "
@@ -1714,23 +1742,27 @@ class FleetStepper:
             if x is None or c is None:
                 raise ValueError("obs-backed stepper needs x= and c= slabs")
             dt = default_float_dtype()
-            slabs = (self._prep_slab(x, np.int32, name="x"),
-                     self._prep_slab(c, dt, name="c"))
-            if self._with_svc:
-                slabs += (self._prep_slab(svc, dt, (self._K,), name="svc"),)
-            elif svc is not None:
-                raise ValueError("stepper built without a svc channel")
-            if self._with_side:
-                slabs += (self._prep_slab(side, np.int32, name="side"),)
-            elif side is not None:
-                raise ValueError("stepper built without a side channel")
+            with _span("step.put", slot=slot):
+                slabs = (self._prep_slab(x, np.int32, name="x"),
+                         self._prep_slab(c, dt, name="c"))
+                if self._with_svc:
+                    slabs += (self._prep_slab(svc, dt, (self._K,),
+                                              name="svc"),)
+                elif svc is not None:
+                    raise ValueError("stepper built without a svc channel")
+                if self._with_side:
+                    slabs += (self._prep_slab(side, np.int32, name="side"),)
+                elif side is not None:
+                    raise ValueError("stepper built without a side channel")
             out = self.step_slabs(slabs)
         if out is None:
             return None
-        if self._fanout:
-            # one [B, chunk] level block per lane, stacked policy-major
-            return np.stack([_local_rows(r)[:self._B] for r in out])
-        return _local_rows(out)[:self._B]
+        # blocks until the device has finished the step
+        with _span("step.readback", slot=slot):
+            if self._fanout:
+                # one [B, chunk] level block per lane, stacked policy-major
+                return np.stack([_local_rows(r)[:self._B] for r in out])
+            return _local_rows(out)[:self._B]
 
     # ---- readbacks ---------------------------------------------------
     # On a process-spanning mesh every readback is this process's own
@@ -2661,10 +2693,10 @@ def offline_opt_fleet(fleet: FleetBatch, *,
         core, args = _dp_scan_core_args(scenario, padded, mesh, n_chunks,
                                         T_pad, checkpointed, collect_schedule,
                                         dp_backend)
-        with shard_ctx(mesh, (FLEET_AXIS,), model_axis=None):
-            out = core(*args)
+        out = _dispatch(mesh, core, *args)
         cost, r_hist = out if collect_schedule else (out, None)
-    cost = _local_rows(cost)[:B].astype(np.float64)
+    with _span("fleet.readback"):
+        cost = _local_rows(cost)[:B].astype(np.float64)
     if not collect_schedule:
         if gather:
             cost = _gather_rows(mesh, cost)

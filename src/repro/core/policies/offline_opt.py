@@ -131,34 +131,36 @@ def dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
 
     Returns ``(J', args [chunk, K])``.
     """
-    K = lv32.shape[-1]
-    # the same float32 w as offline_opt_batch: rent + svc, +inf pads
-    wck = (cck[:, None].astype(jnp.float32) * lv32[None, :]
-           + sck.astype(jnp.float32))
-    wck = jnp.where(kmask[None, :], wck, jnp.inf)
+    # a named scope labels the recursion's ops in a profiler trace
+    with jax.named_scope("dp_fwd"):
+        K = lv32.shape[-1]
+        # the same float32 w as offline_opt_batch: rent + svc, +inf pads
+        wck = (cck[:, None].astype(jnp.float32) * lv32[None, :]
+               + sck.astype(jnp.float32))
+        wck = jnp.where(kmask[None, :], wck, jnp.inf)
 
-    if backend == "pallas":
-        # lazy import: the kernels package (and Pallas) loads only when a
-        # non-default backend is actually requested
-        # every driver hands in contiguous counters t0 + arange(chunk), so
-        # the live slots are the first T_len - t0
-        from repro.kernels.hosting import dp_minplus_kc
-        return dp_minplus_kc(J, wck, fetch_mat, T_len - tids[0])
-    if backend != "xla":
-        raise ValueError(f"backend must be one of {DP_BACKENDS}, "
-                         f"got {backend!r}")
+        if backend == "pallas":
+            # lazy import: the kernels package (and Pallas) loads only when a
+            # non-default backend is actually requested
+            # every driver hands in contiguous counters t0 + arange(chunk), so
+            # the live slots are the first T_len - t0
+            from repro.kernels.hosting import dp_minplus_kc
+            return dp_minplus_kc(J, wck, fetch_mat, T_len - tids[0])
+        if backend != "xla":
+            raise ValueError(f"backend must be one of {DP_BACKENDS}, "
+                             f"got {backend!r}")
 
-    def fwd(J_prev, inp):
-        t, w_t = inp
-        valid_t = t < T_len
-        trans = J_prev[:, None] + fetch_mat
-        arg = jnp.argmin(trans, axis=0)
-        J = jnp.min(trans, axis=0) + w_t
-        J = jnp.where(valid_t, J, J_prev)
-        arg = jnp.where(valid_t, arg, jnp.arange(K))
-        return J, arg
+        def fwd(J_prev, inp):
+            t, w_t = inp
+            valid_t = t < T_len
+            trans = J_prev[:, None] + fetch_mat
+            arg = jnp.argmin(trans, axis=0)
+            J = jnp.min(trans, axis=0) + w_t
+            J = jnp.where(valid_t, J, J_prev)
+            arg = jnp.where(valid_t, arg, jnp.arange(K))
+            return J, arg
 
-    return jax.lax.scan(fwd, J, (tids, wck))
+        return jax.lax.scan(fwd, J, (tids, wck))
 
 
 def dp_backtrack_chunk(k, args):

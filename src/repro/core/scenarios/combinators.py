@@ -103,8 +103,11 @@ def _combine_fns(arr_fns, rent_fns, svc_fns):
         return st
 
     def chunk_fn(params, state, tids):
-        sa, (x, side) = arr_chunk(params["arr"], state["arr"], tids)
-        sr, c = rent_chunk(params["rent"], state["rent"], tids)
+        # named scopes label the two generators' ops in a profiler trace
+        with jax.named_scope("gen_arrivals"):
+            sa, (x, side) = arr_chunk(params["arr"], state["arr"], tids)
+        with jax.named_scope("gen_rents"):
+            sr, c = rent_chunk(params["rent"], state["rent"], tids)
         st = {"arr": sa, "rent": sr}
         svc_v = None
         if svc_fns is not None:

@@ -223,7 +223,9 @@ def sim_chunk_core(step_fn, include_final_fetch: bool,
     xs = (tids, x, c, svc, side)
     if route is not None:
         xs = xs + (route,)
-    return jax.lax.scan(step, carry, xs)
+    # a named scope labels the per-slot scan's ops in a profiler trace
+    with jax.named_scope("policy_step"):
+        return jax.lax.scan(step, carry, xs)
 
 
 def sim_chunk_lanes(step_fns, include_final_fetch: bool,
