@@ -29,9 +29,19 @@ so the identity-keyed compile caches (``base._compiled_gen``, the fleet
 engine's scenario cores) hit instead of re-tracing per construction —
 the legacy ``arrivals.py``/``rentcosts.py`` wrappers build a fresh Stream
 per call and rely on this.
+
+Poisson draws.  ``jax.random.poisson`` draws by Knuth's method below rate
+10 and by transformed rejection at or above it, and under ``vmap`` it runs
+*both* samplers over every lane before selecting one.  ``poisson_arrivals``
+and ``ge_arrivals`` therefore look at their rates when they are built:
+when every rate is concrete and below 10 they take Knuth-only chunk
+functions (``_poisson_knuth``, bit-identical to ``jax.random.poisson`` on
+the same key in that range), else the general ``jax.random.poisson`` path.
+``POISSON_SAMPLER_TRACES`` counts the two at trace time.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional
 
@@ -46,6 +56,13 @@ from repro.core.scenarios.base import (Stream, as_keys, bcast, slot_keys,
 # Salt for draws that must not collide with any per-slot counter (slot
 # counters are the nonnegative slot indices).
 _INIT_SALT = 0x7FFFFFFF
+
+# jax.random.poisson draws by Knuth's method below this rate
+_KNUTH_MAX_RATE = 10.0
+
+# test hook: trace-time counts of Poisson draws by sampler, "knuth" (the
+# Knuth-only sampler) or "general" (jax.random.poisson)
+POISSON_SAMPLER_TRACES = collections.Counter()
 
 
 def _no_state(params):
@@ -77,25 +94,85 @@ def bernoulli_arrivals(key, p, B: int) -> Stream:
                    "flip": jnp.zeros((B,), bool)})
 
 
+def _poisson_knuth(key, lam):
+    """One Poisson(``lam``) draw by Knuth's method, step for step
+    ``jax.random.poisson``'s Knuth sampler: equal to
+    ``jax.random.poisson(key, lam, ())`` bit for bit on the same key for
+    any ``lam < 10`` or NaN, without also running the rejection sampler."""
+    lam = jnp.asarray(lam, jnp.float32)
+
+    def cond(carry):
+        return carry[2] > -lam
+
+    def body(carry):
+        k, rng, log_prod = carry
+        rng, sub = jax.random.split(rng)
+        u = jax.random.uniform(sub, (), jnp.float32)
+        return k + 1, rng, log_prod + jnp.log(u)
+
+    k, _, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), key, jnp.float32(0.0)))
+    return jnp.where(lam == 0, 0, k - 1)
+
+
+def _poisson_draws(ks, lam, knuth: bool):
+    """[chunk] int32 Poisson counts, one per slot key in ``ks``, at a
+    shared scalar ``lam`` or per-slot [chunk] rates."""
+    lam_axis = 0 if jnp.ndim(lam) else None
+    if knuth:
+        POISSON_SAMPLER_TRACES["knuth"] += 1
+        with jax.named_scope("poisson_knuth"):
+            x = jax.vmap(_poisson_knuth, in_axes=(0, lam_axis))(ks, lam)
+    else:
+        POISSON_SAMPLER_TRACES["general"] += 1
+        x = jax.vmap(lambda k, r: jax.random.poisson(k, r, ()),
+                     in_axes=(0, lam_axis))(ks, lam)
+    return x.astype(jnp.int32)
+
+
+def _knuth_rates(*rates) -> bool:
+    """True when every rate is concrete and below 10 (NaN counts as
+    below), so Knuth alone reproduces ``jax.random.poisson``'s draws."""
+    for r in rates:
+        if isinstance(r, jax.core.Tracer):
+            return False
+        if np.any(np.asarray(r, np.float32) >= _KNUTH_MAX_RATE):
+            return False
+    return True
+
+
+def _poisson_chunk_at(params, tids, knuth: bool):
+    x = _poisson_draws(slot_keys(params["key"], tids), params["lam"], knuth)
+    return x, _zeros_side(x)
+
+
 def _poisson_chunk(params, state, tids):
-    ks = slot_keys(params["key"], tids)
-    x = jax.vmap(lambda k: jax.random.poisson(k, params["lam"], ()))(ks)
-    return state, (x.astype(jnp.int32), _zeros_side(x))
+    return state, _poisson_chunk_at(params, tids, False)
+
+
+def _poisson_chunk_knuth(params, state, tids):
+    return state, _poisson_chunk_at(params, tids, True)
 
 
 def poisson_arrivals(key, lam, B: int) -> Stream:
-    return Stream("poisson", "arrivals", _no_state, _poisson_chunk,
+    """Poisson(lam) arrivals; ``lam`` scalar or per-instance [B].
+
+    A concrete ``lam`` below 10 everywhere selects the Knuth-only sampler
+    at construction.  Such a stream must not be given params with a rate
+    of 10 or more later: it would still draw Poisson counts, but not
+    ``jax.random.poisson``'s bits."""
+    chunk = _poisson_chunk_knuth if _knuth_rates(lam) else _poisson_chunk
+    return Stream("poisson", "arrivals", _no_state, chunk,
                   {"key": as_keys(key, B),
                    "lam": bcast(lam, B, jnp.float32)})
 
 
 def _ge_emit(key, tids, rates, emission: str, salt: int):
     """Per-slot emissions at per-slot rates (counter-keyed)."""
-    if emission == "poisson":
+    if emission in ("poisson", "poisson_knuth"):
         ks = slot_keys(key, tids)
         ks = jax.vmap(lambda k: jax.random.fold_in(k, salt))(ks)
-        return jax.vmap(
-            lambda k, r: jax.random.poisson(k, r, ()))(ks, rates).astype(jnp.int32)
+        return _poisson_draws(ks, rates, emission == "poisson_knuth")
     if emission == "bernoulli":
         # the fold/salt/uniform chain IS slot_uniform's — draw through it
         # so GE bernoulli emissions ride the PRNG backend dispatch too
@@ -136,6 +213,10 @@ def _ge_chunk_poisson(params, state, tids):
     return _ge_chunk(params, state, tids, "poisson")
 
 
+def _ge_chunk_poisson_knuth(params, state, tids):
+    return _ge_chunk(params, state, tids, "poisson_knuth")
+
+
 def _ge_chunk_bernoulli(params, state, tids):
     return _ge_chunk(params, state, tids, "bernoulli")
 
@@ -143,9 +224,17 @@ def _ge_chunk_bernoulli(params, state, tids):
 def ge_arrivals(key, p_hl, p_lh, rate_h, rate_l, B: int,
                 emission: str = "poisson") -> Stream:
     """Gilbert-Elliot Markov-modulated arrivals; ``side`` carries the chain
-    state (1 = H), which is what the MDP/ABC baselines observe."""
+    state (1 = H), which is what the MDP/ABC baselines observe.
+
+    Poisson emissions with concrete ``rate_h`` and ``rate_l`` below 10
+    everywhere select the Knuth-only sampler at construction.  Such a
+    stream must not be given params with a rate of 10 or more later: it
+    would still draw Poisson counts, but not ``jax.random.poisson``'s
+    bits."""
     chunk = {"poisson": _ge_chunk_poisson,
              "bernoulli": _ge_chunk_bernoulli}[emission]
+    if emission == "poisson" and _knuth_rates(rate_h, rate_l):
+        chunk = _ge_chunk_poisson_knuth
     return Stream(f"ge-{emission}", "arrivals", _ge_init, chunk,
                   {"key": as_keys(key, B),
                    "p_hl": bcast(p_hl, B, jnp.float32),
