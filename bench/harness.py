@@ -4,7 +4,9 @@ Everything a cell is made of is found by name under ``bench/``:
 
 * ``BENCHMARK.json`` (at the checkout root) names the cell's configuration,
   traffic mix and chips, and the metrics with the cells each applies to;
-* ``bench/configs/<config>.json`` is the deployment;
+* ``bench/configs/<config>.json`` is the deployment, as it runs on the
+  chip; its ``cpu_cut`` holds the keys that the CPU tests override to cut
+  it to a CPU-sized scale, and nothing run on the chip reads it;
 * ``bench/traffic/<traffic>.json`` is the traffic mix: its ``driver`` names
   ``bench/traffic/<driver>.py``, which builds the deployment (``setup``),
   drives the program for the window (``window``) and compares what the
@@ -12,8 +14,8 @@ Everything a cell is made of is found by name under ``bench/``:
 * ``bench/metrics/<metric>.py`` reduces the run to one number (``read``),
   or to nothing where it finds nothing to read.
 
-A later change adds a cell, a mix or a metric by adding files and entries,
-without editing a file that is here.
+A later change adds a configuration, a cell, a mix or a metric by adding
+files and entries, without editing a file that is here.
 """
 from __future__ import annotations
 

@@ -18,17 +18,21 @@ from bench import compcache, harness, peaks
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
-TINY = {"metro_edge_fleet": dict(sites=64),
-        "whatif_pricing": dict(sites=8, horizon_slots=384, chunk_slots=128)}
 
 
-def tiny_spec(dest: Path) -> Path:
-    """BENCHMARK.json with every configuration cut to a CPU-sized scale."""
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+def tiny_spec(dest: Path, source: Path = ROOT / "BENCHMARK.json") -> Path:
+    """``source`` with every configuration cut to a CPU-sized scale: each
+    configuration's file states its own cut under ``cpu_cut``."""
+    spec = json.loads(source.read_text())
     (dest / "configs").mkdir(parents=True, exist_ok=True)
     for c in spec["configs"]:
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        cfg.update(TINY[c["name"]])
+        path = source.parent / c["file"]
+        cfg = json.loads(path.read_text())
+        if "cpu_cut" not in cfg:
+            raise ValueError(f"{path} states no cpu_cut: add the keys "
+                             "that cut this configuration to a CPU-sized "
+                             "scale")
+        cfg.update(cfg["cpu_cut"])
         c["file"] = f"configs/{c['name']}.json"
         (dest / c["file"]).write_text(json.dumps(cfg))
     (dest / "BENCHMARK.json").write_text(json.dumps(spec))
@@ -41,6 +45,34 @@ def run_cell(spec_path, name, n_devices=1, seconds=0.5):
                       trace=False, t0=time.perf_counter(),
                       devices=jax.devices()[:n_devices])
     return harness.execute(run)
+
+
+def _synthetic_spec(root: Path, cfg: dict) -> Path:
+    """A spec with one configuration under a name no real one has."""
+    (root / "configs").mkdir(parents=True)
+    (root / "configs" / "probe_fleet.json").write_text(json.dumps(cfg))
+    spec = {"configs": [{"name": "probe_fleet",
+                         "file": "configs/probe_fleet.json"}],
+            "workloads": [], "end_to_end": [], "per_layer": []}
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root / "BENCHMARK.json"
+
+
+def test_tiny_spec_applies_each_files_cpu_cut(tmp_path):
+    src = _synthetic_spec(tmp_path / "src", {
+        "sites": 4096, "seeds": 3, "horizon_slots": 32768,
+        "cpu_cut": {"sites": 16, "horizon_slots": 256}})
+    out = tiny_spec(tmp_path / "out", src)
+    spec = json.loads(out.read_text())
+    (c,) = spec["configs"]
+    cfg = json.loads((out.parent / c["file"]).read_text())
+    assert (cfg["sites"], cfg["seeds"], cfg["horizon_slots"]) == (16, 3, 256)
+
+
+def test_tiny_spec_names_the_file_without_cpu_cut(tmp_path):
+    src = _synthetic_spec(tmp_path / "src", {"sites": 4096})
+    with pytest.raises(ValueError, match="probe_fleet.json.*cpu_cut"):
+        tiny_spec(tmp_path / "out", src)
 
 
 @pytest.fixture
@@ -152,7 +184,7 @@ FOUR_DEVICES = textwrap.dedent('''
     peaks.PEAKS[jax.devices()[0].device_kind] = peaks.PEAKS["TPU v5 lite"]
     compcache.enable = lambda: None
     spec = T.tiny_spec(Path(sys.argv[2]))
-    cell = "whatif.lanes_opt"
+    cell = sys.argv[3]
     sound = T.run_cell(spec, cell, n_devices=4)["correct"]
     from repro.core import fleet as F
     run_fleet, opt = F.run_fleet, F.offline_opt_fleet
@@ -176,14 +208,15 @@ FOUR_DEVICES = textwrap.dedent('''
     ''')
 
 
-def test_exchange_between_chips_left_out(tmp_path):
-    """The pricing cell on a fleet mesh of four host devices, rows checked
+@pytest.mark.parametrize("cell", [PRICING, "whatif.lanes_only"])
+def test_exchange_between_chips_left_out(tmp_path, cell):
+    """A pricing cell on a fleet mesh of four host devices, rows checked
     from every shard: sound is correct; results read from the first chip's
     shard only are not."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     p = subprocess.run([sys.executable, "-c", FOUR_DEVICES, str(ROOT),
-                        str(tmp_path)], env=env, capture_output=True,
+                        str(tmp_path), cell], env=env, capture_output=True,
                        text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
     assert json.loads(p.stdout.strip().splitlines()[-1]) == {
