@@ -24,11 +24,15 @@ N=1-identical to the single-service paths (tests/test_multi_service.py):
   the joint states — capacity-respecting by construction, proven against
   ``policies.offline_opt.brute_force_joint_opt``.
 
+The N-to-joint reduction runs under the named scope ``joint_reduce``, and
+``JOINT_SCENARIO_TRACES`` counts its traces by ``(N, J)``.
+
 Engine-invariant documentation lives in docs/ARCHITECTURE.md and
 docs/CONVENTIONS.md.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Optional, Sequence, Tuple
@@ -47,6 +51,10 @@ from repro.core.policies.base import PolicyFns
 from repro.core.scenarios.base import ObsSlab, Scenario
 from repro.core.scenarios.combinators import tile_services
 
+# test hook: trace-time counts of joint-scenario reductions, keyed by
+# (services N, joint states J)
+JOINT_SCENARIO_TRACES = collections.Counter()
+
 
 @dataclasses.dataclass(frozen=True)
 class ServiceFleet:
@@ -55,6 +63,10 @@ class ServiceFleet:
 
     sets: Tuple[ServiceSet, ...]
     T: np.ndarray                      # [B] per-instance horizons
+    # the joint-state tables, built on first use and kept: the sets are
+    # immutable, and at fleet width a build takes seconds on the host
+    _joint: dict = dataclasses.field(default_factory=dict, init=False,
+                                     repr=False, compare=False)
 
     def __post_init__(self):
         object.__setattr__(self, "sets", tuple(self.sets))
@@ -90,8 +102,33 @@ class ServiceFleet:
                                        np.repeat(self.T, self.N))
 
     def joint_grid(self) -> HostingGrid:
-        """[B] matrix-M joint-state grid (mixed state counts padded)."""
-        return joint_hosting_grid(list(self.sets))
+        """[B] matrix-M joint-state grid (mixed state counts padded), built
+        once per fleet."""
+        if "grid" not in self._joint:
+            self._joint["grid"] = joint_hosting_grid(list(self.sets))
+        return self._joint["grid"]
+
+    def joint_tables(self) -> Tuple[np.ndarray, np.ndarray]:
+        """``(idx, g_lane)``, each [B, N, J]: every joint state's level index
+        (int32) and service cost (float32) per service, built once per
+        fleet.  Padded joint states of mixed-J fleets repeat their set's
+        last real state."""
+        if "tables" not in self._joint:
+            B, N = self.B, self.N
+            J = max(ss.J for ss in self.sets)
+            idx = np.zeros((B, N, J), np.int32)
+            g_lane = np.zeros((B, N, J), np.float32)
+            for b, ss in enumerate(self.sets):
+                st = ss.joint_states()                  # [J_b, N]
+                Jb = st.shape[0]
+                idx[b, :, :Jb] = st.T
+                idx[b, :, Jb:] = idx[b, :, Jb - 1:Jb]
+                for n, cc in enumerate(ss.services):
+                    g_lane[b, n, :Jb] = np.asarray(cc.g,
+                                                   np.float32)[st[:, n]]
+                    g_lane[b, n, Jb:] = g_lane[b, n, Jb - 1]
+            self._joint["tables"] = (idx, g_lane)
+        return self._joint["tables"]
 
     def joint_fleet(self) -> FleetBatch:
         """The obs-less [B] joint fleet (pair with ``joint_scenario``)."""
@@ -253,21 +290,25 @@ def _joint_fns(sub_init, sub_chunk, has_svc: bool, has_side: bool):
             params["sub"], state)
         x_sub = slab.x                                  # [N, chunk]
         idx = params["idx"]                             # [N, J] int32
-        if has_svc:
-            svc_sub = slab.svc                          # [N, chunk, K]
-            N, chunk = x_sub.shape
-            J = idx.shape[-1]
-            gathered = jnp.take_along_axis(
-                svc_sub, jnp.broadcast_to(idx[:, None, :], (N, chunk, J)),
-                axis=2)                                 # [N, chunk, J]
-            svc = jnp.sum(gathered, axis=0)
-        else:
-            g_lane = params["g_lane"]                   # [N, J]
-            svc = jnp.sum(x_sub[:, :, None].astype(g_lane.dtype)
-                          * g_lane[:, None, :], axis=0)
-        side = None if slab.side is None else slab.side[0]
-        return st2, ObsSlab(x=jnp.sum(x_sub, axis=0), c=slab.c[0], svc=svc,
-                            side=side)
+        N, chunk = x_sub.shape
+        J = idx.shape[-1]
+        JOINT_SCENARIO_TRACES[(N, J)] += 1
+        # a named scope labels the reduction's ops in a profiler trace
+        with jax.named_scope("joint_reduce"):
+            if has_svc:
+                svc_sub = slab.svc                      # [N, chunk, K]
+                gathered = jnp.take_along_axis(
+                    svc_sub,
+                    jnp.broadcast_to(idx[:, None, :], (N, chunk, J)),
+                    axis=2)                             # [N, chunk, J]
+                svc = jnp.sum(gathered, axis=0)
+            else:
+                g_lane = params["g_lane"]               # [N, J]
+                svc = jnp.sum(x_sub[:, :, None].astype(g_lane.dtype)
+                              * g_lane[:, None, :], axis=0)
+            side = None if slab.side is None else slab.side[0]
+            return st2, ObsSlab(x=jnp.sum(x_sub, axis=0), c=slab.c[0],
+                                svc=svc, side=side)
 
     return init_fn, chunk_fn
 
@@ -280,17 +321,7 @@ def joint_scenario(sfleet: ServiceFleet, scenario: Scenario) -> Scenario:
     state — priced ``+inf`` by the grid mask, never selected."""
     tiled = service_scenario(sfleet, scenario)
     B, N = sfleet.B, sfleet.N
-    J = max(ss.J for ss in sfleet.sets)
-    idx = np.zeros((B, N, J), np.int32)
-    g_lane = np.zeros((B, N, J), np.float32)
-    for b, ss in enumerate(sfleet.sets):
-        st = ss.joint_states()                          # [J_b, N]
-        Jb = st.shape[0]
-        idx[b, :, :Jb] = st.T
-        idx[b, :, Jb:] = idx[b, :, Jb - 1:Jb]
-        for n, cc in enumerate(ss.services):
-            g_lane[b, n, :Jb] = np.asarray(cc.g, np.float32)[st[:, n]]
-            g_lane[b, n, Jb:] = g_lane[b, n, Jb - 1]
+    idx, g_lane = sfleet.joint_tables()
     params = {"sub": _reshape_sub(tiled.params, B, N),
               "idx": jnp.asarray(idx),
               "g_lane": jnp.asarray(g_lane, default_float_dtype())}

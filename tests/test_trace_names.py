@@ -4,7 +4,9 @@
   core with generation, the checkpointed scenario DP core) carry
   ``gen_arrivals``, ``gen_rents``, ``policy_step`` and ``dp_fwd`` in their
   HLO's ``op_name`` metadata, which is where a profiler finds an op's
-  stage.
+  stage.  The joint multi-service DP also names its N-to-joint reduction
+  ``joint_reduce``, and ``services.JOINT_SCENARIO_TRACES`` counts the
+  reduction's traces by ``(N, J)``.
 * **Host spans** — each ``LiveFleetScheduler.admit`` records exactly one
   ``repro.step.put``, ``repro.step.dispatch`` and ``repro.step.readback``
   span, in that order, each with the admitted slot's index.
@@ -21,7 +23,8 @@ from jax.profiler import ProfileData
 
 from repro.core import fleet as F
 from repro.core import scenarios as S
-from repro.core.costs import HostingCosts, HostingGrid
+from repro.core import services as SV
+from repro.core.costs import HostingCosts, HostingGrid, ServiceSet
 from repro.core.policies import AlphaRR, RetroRenting
 from repro.serve.scheduler import LiveFleetScheduler
 
@@ -83,6 +86,54 @@ def test_pricing_programs_name_their_stages(monkeypatch):
                                     chunk_size=CHUNK, checkpointed=True,
                                     collect_schedule=False))
     assert _scopes(opt_text) == {"gen_arrivals", "gen_rents", "dp_fwd"}
+
+
+def _joint_fleet(B: int, N: int, capacity: float, T_len: int):
+    sets = [ServiceSet((HostingCosts.three_level(10.0, 0.4, 0.35),) * N,
+                       capacity=capacity)] * B
+    return SV.service_fleet(sets, T_len)
+
+
+def _joint_scenario(B: int, seed: int):
+    k_arr, k_rent = jax.random.split(jax.random.PRNGKey(seed))
+    return S.combine(
+        S.ge_arrivals(S.split_keys(k_arr, B), 0.3, 0.2, 2.0, 0.2, B),
+        S.arma_rents(k_rent, 0.35, B))
+
+
+def _joint_opt(sf, seed: int, chunk: int):
+    return SV.offline_opt_services(sf, scenario=_joint_scenario(sf.B, seed),
+                                   n_seeds=2, chunk_size=chunk,
+                                   checkpointed=True, collect_schedule=False)
+
+
+def test_joint_dp_names_its_reduction(monkeypatch):
+    sf = _joint_fleet(B, 3, 1.0, T)
+    text = _lowered_hlo(monkeypatch, "_compiled_dp_ckpt_scenario_core",
+                        lambda: _joint_opt(sf, 3, CHUNK))
+    # the per-service generator runs under a vmap over the services, which
+    # wraps its scopes' names: ``vmap(gen_arrivals)``
+    parts = {re.sub(r"^(?:vmap\()+(.*?)\)*$", r"\1", part)
+             for p in re.findall(r'op_name="([^"]*)"', text)
+             for part in p.split("/")}
+    assert {"joint_reduce", "gen_arrivals", "gen_rents", "dp_fwd"} <= parts
+
+
+def test_joint_scenario_traces_count_new_shapes():
+    """One trace per new (N, J) program shape, none on a cache hit."""
+    def traced(sf, seed, chunk):
+        before = dict(SV.JOINT_SCENARIO_TRACES)
+        _joint_opt(sf, seed, chunk)
+        return {k: v - before.get(k, 0)
+                for k, v in SV.JOINT_SCENARIO_TRACES.items()
+                if v != before.get(k, 0)}
+
+    # shapes no other test of the suite runs: 5 sites, 40 slots, chunk 8
+    sf3, sf4 = _joint_fleet(5, 3, 1.0, 40), _joint_fleet(5, 4, 2.0, 40)
+    assert traced(sf3, 1, 8) == {(3, 10): 1}
+    assert traced(sf3, 2, 8) == {}                  # new keys, same program
+    assert traced(sf4, 1, 8) == {(4, 50): 1}
+    assert traced(sf4, 2, 8) == {}
 
 
 def _host_events(log_dir: str):
