@@ -48,12 +48,15 @@ fused simulator relies on.  Bit-identical to the materialized path
 wherever both fit (the recomputed tables come from the identical
 ``offline_opt.dp_fwd_chunk`` at the identical frontier), with device
 memory O(B * chunk * K): exact OPT now reaches the same T = 10^6-10^7
-horizons as ``run_fleet(collect_trace=False)``.  ``stream=True`` drives
-both passes from the host one slab at a time; ``collect_schedule=False``
-skips the backtrack for cost-only pricing with no O(T) output at all;
-``offline_dp_memory_stats`` exposes the XLA-reported memory of the
-compiled core for either path (the regression-gated
-``kernel_bench.offline_dp_streaming`` row asserts the ratio).
+horizons as ``run_fleet(collect_trace=False)``.  The forward passes that
+keep no table call ``offline_opt.dp_fwd_frontier``, which runs wide
+joint-state frontiers row-blocked on TPU with the same bits.
+``stream=True`` drives both passes from the host one slab at a time;
+``collect_schedule=False`` skips the backtrack for cost-only pricing with
+no O(T) output at all; ``offline_dp_memory_stats`` exposes the
+XLA-reported memory of the compiled core for either path (the
+regression-gated ``kernel_bench.offline_dp_streaming`` row asserts the
+ratio).
 
 **Scenario fusion** — every entry point alternatively accepts
 ``scenario=...`` (a ``core.scenarios.Scenario``) in place of materialized
@@ -197,8 +200,9 @@ call: ``repro.step.put`` / ``.dispatch`` / ``.readback`` in
 ``run_fleet`` and ``offline_opt_fleet``.  On the device, ``jax.named_scope``
 puts ``gen_arrivals`` / ``gen_rents`` (the combined scenario
 ``chunk_fn``), ``policy_step`` (``sim_chunk_core``) and ``dp_fwd``
-(``dp_fwd_chunk``) in each op's ``op_name`` metadata; scopes change no op
-(tests/test_trace_names.py).
+(``dp_fwd_chunk``; the row-blocked frontier-only form nests
+``dp_fwd_rows`` in it) in each op's ``op_name`` metadata; scopes change no
+op (tests/test_trace_names.py).
 """
 from __future__ import annotations
 
@@ -220,7 +224,7 @@ from repro.core.policies.base import PolicyFns, PolicyLane, as_policy_lanes
 from repro.core.policies.offline_opt import (DP_BACKENDS, dp_backtrack,
                                              dp_backtrack_chunk,
                                              dp_fetch_matrix, dp_frontier0,
-                                             dp_fwd_chunk)
+                                             dp_fwd_chunk, dp_fwd_frontier)
 from repro.core.scenarios.base import PRNG_BACKENDS, Scenario, chunk_geometry
 from repro.core.scenarios.combinators import (replicate_seeds,
                                               with_prng_backend)
@@ -2190,8 +2194,8 @@ def _make_dp_ckpt_instance_core(n_chunks: int, has_svc: bool,
                                 dp_backend: str = "xla"):
     """Checkpointed DP for ONE instance, obs-backed.
 
-    Pass 1 runs ``dp_fwd_chunk`` over the chunks, emitting each chunk's
-    *entry* frontier (a [K] row) instead of its [chunk, K] argmin table;
+    Pass 1 runs ``dp_fwd_frontier`` (``dp_fwd_chunk`` without the table)
+    over the chunks, emitting each chunk's *entry* frontier (a [K] row);
     pass 2 scans the chunks in reverse, recomputing each table from its
     checkpoint with the *same* ``dp_fwd_chunk`` and backtracking through it
     (``dp_backtrack_chunk``), chaining ``k`` right-to-left.  The (k, arg)
@@ -2213,16 +2217,16 @@ def _make_dp_ckpt_instance_core(n_chunks: int, has_svc: bool,
         xs, cs, ss = cut(x), cut(c), cut(svc)
         t0s = jnp.arange(n_chunks, dtype=jnp.int32) * chunk
 
-        def chunk_pass(J, t0, xck, cck, sck):
+        def chunk_pass(J, t0, xck, cck, sck, relax=dp_fwd_chunk):
             if sck is None:
                 sck = _model1_svc(xck, g)
             tids = t0 + jnp.arange(chunk, dtype=jnp.int32)
-            return dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat,
-                                T_len, dp_backend)
+            return relax(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
+                         dp_backend)
 
         def fwd(J, inp):
             t0, xck, cck, sck = inp
-            J2, _ = chunk_pass(J, t0, xck, cck, sck)
+            J2 = chunk_pass(J, t0, xck, cck, sck, dp_fwd_frontier)
             return J2, J                    # checkpoint = chunk-ENTRY frontier
 
         J_T, ckpts = jax.lax.scan(fwd, dp_frontier0(K), (t0s, xs, cs, ss))
@@ -2260,16 +2264,15 @@ def _make_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
         chunk = T_pad // n_chunks
         tcks = tids_all.reshape(n_chunks, chunk)
 
-        def chunk_pass(J, gen_state, tids):
+        def chunk_pass(J, gen_state, tids, relax=dp_fwd_chunk):
             gen2, slab = sc_chunk(sparams, gen_state, tids)
             sck = slab.svc if slab.svc is not None else _model1_svc(slab.x, g)
-            J2, args = dp_fwd_chunk(J, tids, slab.c, sck, lv32, kmask,
-                                    fetch_mat, T_len, dp_backend)
-            return gen2, J2, args
+            return gen2, relax(J, tids, slab.c, sck, lv32, kmask,
+                               fetch_mat, T_len, dp_backend)
 
         def fwd(carry, tids):
             gen_state, J = carry
-            gen2, J2, _ = chunk_pass(J, gen_state, tids)
+            gen2, J2 = chunk_pass(J, gen_state, tids, dp_fwd_frontier)
             return (gen2, J2), (gen_state, J)      # entry-state checkpoints
 
         carry0 = (sc_init(sparams), dp_frontier0(K))
@@ -2281,7 +2284,7 @@ def _make_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
 
         def bwd(k, inp):
             gen_ck, Jck, tids = inp
-            _, _, args = chunk_pass(Jck, gen_ck, tids)
+            _, (_, args) = chunk_pass(Jck, gen_ck, tids)
             return dp_backtrack_chunk(k, args)
 
         _, r = jax.lax.scan(bwd, k_T, (gen_ckpts, J_ckpts, tcks),
@@ -2359,9 +2362,8 @@ def _compiled_dp_stream_fwd(has_svc: bool, mesh: Mesh,
         fetch_mat = dp_fetch_matrix(M.astype(jnp.float32), lv32)
         sck = opt[0] if has_svc else _model1_svc(xck, g)
         tids = t0 + jnp.arange(xck.shape[-1], dtype=jnp.int32)
-        J2, _ = dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat,
-                             T_len, dp_backend)
-        return J2
+        return dp_fwd_frontier(J, tids, cck, sck, lv32, kmask, fetch_mat,
+                               T_len, dp_backend)
 
     n_opt = int(has_svc)
     in_axes = (0, 0, 0, 0, 0, None, 0, 0, 0) + (0,) * n_opt
@@ -2414,9 +2416,8 @@ def _compiled_dp_scenario_stream_fwd(sc_init, sc_chunk, chunk: int,
         tids = t0 + jnp.arange(chunk, dtype=jnp.int32)
         gen2, slab = sc_chunk(sparams, gen_state, tids)
         sck = slab.svc if slab.svc is not None else _model1_svc(slab.x, g)
-        J2, _ = dp_fwd_chunk(J, tids, slab.c, sck, lv32, kmask, fetch_mat,
-                             T_len, dp_backend)
-        return gen2, J2
+        return gen2, dp_fwd_frontier(J, tids, slab.c, sck, lv32, kmask,
+                                     fetch_mat, T_len, dp_backend)
 
     spec = P(FLEET_AXIS)
     sharded = _fleet_map(

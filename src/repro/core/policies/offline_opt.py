@@ -43,12 +43,25 @@ not allclose — in tests/test_kernels.py and tests/test_backend_dispatch.py
 for every driver configuration; any future backend must ship the same
 proof before the fleet layer will thread it (ROADMAP engine invariants).
 
+**Frontier-only passes** (pass 1 of the checkpointed cores, the streamed
+forward steps) discard the argmin table and call ``dp_fwd_frontier``.  On
+TPU, with the default backend, a batch of rows whose frontier width lies
+in ``[DP_ROWS_MIN_K, DP_ROWS_MAX_K]`` (the joint multi-service states)
+runs through ``kernels.hosting.dp_minplus_rows`` instead of the vmapped
+scan: rows on lanes, each row block's fetch matrices read from HBM once
+per chunk instead of once per slot.  The choice follows the width and the
+platform the program lowers for, and the frontiers are bitwise the scan's
+(tests/test_dp_rows_kernel.py); narrower frontiers keep the scan's
+program unchanged.
+
 ``OPT`` (no partial hosting, the benchmark of [22]) is the same DP on the
 2-level instance. Exhaustive-search cross-checks live in the tests.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -161,6 +174,84 @@ def dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
             return J, arg
 
         return jax.lax.scan(fwd, J, (tids, wck))
+
+
+#: Frontier widths at which the batched, frontier-only forward runs the
+#: row-blocked kernel ``kernels.hosting.dp_minplus_rows`` on TPU.  From 8:
+#: on a v5e the kernel beats the vmapped scan at every width swept from 8
+#: to 50, while at 3 it is no faster than the scan inside a pricing
+#: program (PERF.md, the K sweep).  Up to 64: a row block's fetch matrices
+#: then take at most 2 MiB of VMEM per buffer.
+DP_ROWS_MIN_K = 8
+DP_ROWS_MAX_K = 64
+
+# test hook: trace-time counts of the row-blocked forward, keyed by
+# (frontier width K, chunk)
+DP_ROWS_TRACES = collections.Counter()
+
+
+def dp_rows_platforms(K: int) -> tuple:
+    """The platforms on which a batched, frontier-only forward of width
+    ``K`` runs ``dp_minplus_rows``: TPU for ``DP_ROWS_MIN_K <= K <=
+    DP_ROWS_MAX_K``, none otherwise.  Elsewhere the ``lax.scan`` stays."""
+    return ("tpu",) if DP_ROWS_MIN_K <= K <= DP_ROWS_MAX_K else ()
+
+
+def dp_fwd_frontier(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
+                    backend: str = "xla"):
+    """``dp_fwd_chunk`` for the passes that discard the argmin table: the
+    frontier ``J'`` alone, bitwise the same.  Under ``jax.vmap`` with the
+    default backend, a width that ``dp_rows_platforms`` lists runs every
+    row of the batch through the row-blocked kernel on those platforms
+    (``_dp_fwd_rows``); every other call is ``dp_fwd_chunk``."""
+    if backend == "xla" and dp_rows_platforms(lv32.shape[-1]):
+        return _dp_fwd_rows(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len)
+    return dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
+                        backend)[0]
+
+
+def _dp_fwd_scan(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len):
+    return dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len)[0]
+
+
+_dp_fwd_rows = jax.custom_batching.custom_vmap(_dp_fwd_scan)
+
+
+def _dp_fwd_rows_kernel(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len, *,
+                        interpret: bool):
+    """The batched frontier-only forward through ``dp_minplus_rows``: every
+    operand is built rows-minor (one row per lane) as the kernel reads it,
+    with ``dp_fwd_chunk``'s float32 ops for the holding costs."""
+    from repro.kernels.hosting import dp_minplus_rows
+    with jax.named_scope("dp_fwd"), jax.named_scope("dp_fwd_rows"):
+        f32 = jnp.float32
+        wT = (cck.T.astype(f32)[:, None, :] * lv32.T[None, :, :]
+              + jnp.transpose(sck.astype(f32), (1, 2, 0)))
+        wT = jnp.where(kmask.T[None, :, :], wT, jnp.inf)
+        out = dp_minplus_rows(J.T, wT, jnp.transpose(fetch_mat, (1, 2, 0)),
+                              T_len - tids[:, 0], interpret=interpret)
+        return out.T
+
+
+@_dp_fwd_rows.def_vmap
+def _dp_fwd_rows_vmap(axis_size, in_batched, J, tids, cck, sck, lv32, kmask,
+                      fetch_mat, T_len):
+    """The batching rule: the kernel on the platforms that
+    ``dp_rows_platforms`` lists (in interpret mode off the chip), the
+    vmapped scan on every other; the choice is made where the program
+    lowers.  Unbatched operands (the fleet cores share ``tids``) are
+    broadcast to the batch first."""
+    args = (J, tids, cck, sck, lv32, kmask, fetch_mat, T_len)
+    K, chunk = lv32.shape[-1], tids.shape[-1]
+    DP_ROWS_TRACES[(K, chunk)] += 1
+    rows = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, b in zip(args, in_batched)]
+    kernels = {p: functools.partial(_dp_fwd_rows_kernel,
+                                    interpret=p != "tpu")
+               for p in dp_rows_platforms(K)}
+    out = jax.lax.platform_dependent(*rows, default=jax.vmap(_dp_fwd_scan),
+                                     **kernels)
+    return out, True
 
 
 def dp_backtrack_chunk(k, args):

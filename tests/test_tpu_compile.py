@@ -4,8 +4,11 @@ Nothing runs: each test lowers and compiles for one chip of a ``v5e:2x2``
 topology that the installed TPU compiler describes without the hardware,
 so what Mosaic or XLA:TPU would refuse (block shapes, VMEM, HBM) fails
 here.  The kernels are compiled with ``interpret=False`` in the vmapped
-form the fleet engine runs; the XLA programs at the fleet width the chip
-smoke run uses (B = 4096, chunk = 4096).  The topology is described inside
+form the fleet engine runs (the row-blocked DP forward in the rows-minor
+form it takes, at the multi-tenant cell's width); the XLA programs at the
+fleet width the chip smoke run uses (B = 4096, chunk = 4096).  A lowering
+for the chip also shows which frontier widths take the row-blocked
+kernel.  The topology is described inside
 a fixture, never at import time, and every compile runs in the test's own
 process.
 """
@@ -19,9 +22,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.costs import HostingCosts
 from repro.core.policies.alpha_rr import (alpha_rr_init, alpha_rr_params,
                                           alpha_rr_step)
-from repro.core.policies.offline_opt import dp_fwd_chunk
+from repro.core.policies.offline_opt import (DP_ROWS_TRACES, dp_fwd_chunk,
+                                             dp_fwd_frontier)
 from repro.core.simulator import sim_acc0, sim_chunk_core
-from repro.kernels.hosting import dp_minplus_kc, slot_uniform_tc
+from repro.kernels.hosting import (dp_minplus_kc, dp_minplus_rows,
+                                   slot_uniform_tc)
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
 CHUNK = 4096
@@ -70,6 +75,43 @@ def test_dp_minplus_kernel_compiles_vmapped(one_chip, K):
         _sds(one_chip, (B, K, K), f32),
         _sds(one_chip, (B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("K", [50, 64])
+def test_dp_minplus_rows_compiles_at_the_joint_width(one_chip, K):
+    """The row-blocked forward at the multi-tenant cell's size (16384 rows,
+    J = 50 joint states, a chunk of 512 slots) and at the widest frontier
+    the engine gives it."""
+    R, chunk = 16384, 512
+    f32 = jnp.float32
+    kernel = functools.partial(dp_minplus_rows, interpret=False)
+    compiled = jax.jit(kernel).lower(
+        _sds(one_chip, (K, R), f32), _sds(one_chip, (chunk, K, R), f32),
+        _sds(one_chip, (K, K, R), f32),
+        _sds(one_chip, (R,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("K", [3, 50])
+def test_frontier_forward_takes_the_rows_kernel_by_width(one_chip, K):
+    """Lowered for the chip, the batched frontier-only forward runs
+    ``dp_minplus_rows`` at J = 50 and counts the trace; at K = 3 it keeps
+    the scan and counts nothing."""
+    B, chunk = 384, 40                      # shapes no other test traces
+    f32 = jnp.float32
+    step = jax.vmap(lambda *a: dp_fwd_frontier(*a),
+                    in_axes=(0, None, 0, 0, 0, 0, 0, 0))
+    before = DP_ROWS_TRACES[(K, chunk)]
+    compiled = jax.jit(step).lower(
+        _sds(one_chip, (B, K), f32), _sds(one_chip, (chunk,), jnp.int32),
+        _sds(one_chip, (B, chunk), f32), _sds(one_chip, (B, chunk, K), f32),
+        _sds(one_chip, (B, K), f32), _sds(one_chip, (B, K), jnp.bool_),
+        _sds(one_chip, (B, K, K), f32),
+        _sds(one_chip, (B,), jnp.int32)).compile()
+    rows = K == 50
+    assert ("tpu_custom_call" in compiled.as_text()) == rows
+    assert DP_ROWS_TRACES[(K, chunk)] - before == int(rows)
 
 
 @pytest.mark.parametrize("salt", [None, 1])

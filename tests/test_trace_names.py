@@ -6,12 +6,16 @@
   HLO's ``op_name`` metadata, which is where a profiler finds an op's
   stage.  The joint multi-service DP also names its N-to-joint reduction
   ``joint_reduce``, and ``services.JOINT_SCENARIO_TRACES`` counts the
-  reduction's traces by ``(N, J)``.
+  reduction's traces by ``(N, J)``.  Where a wide frontier's forward runs
+  row-blocked (``kernels.hosting.dp_minplus_rows``), that path runs under
+  ``dp_fwd_rows`` inside ``dp_fwd``, and ``offline_opt.DP_ROWS_TRACES``
+  counts its traces by ``(K, chunk)``.
 * **Host spans** — each ``LiveFleetScheduler.admit`` records exactly one
   ``repro.step.put``, ``repro.step.dispatch`` and ``repro.step.readback``
   span, in that order, each with the admitted slot's index.
 """
 import glob
+import importlib
 import os
 import re
 
@@ -27,6 +31,8 @@ from repro.core import services as SV
 from repro.core.costs import HostingCosts, HostingGrid, ServiceSet
 from repro.core.policies import AlphaRR, RetroRenting
 from repro.serve.scheduler import LiveFleetScheduler
+
+O = importlib.import_module("repro.core.policies.offline_opt")
 
 B, T, CHUNK = 4, 64, 16
 SCOPES = ("gen_arrivals", "gen_rents", "policy_step", "dp_fwd")
@@ -134,6 +140,43 @@ def test_joint_scenario_traces_count_new_shapes():
     assert traced(sf3, 2, 8) == {}                  # new keys, same program
     assert traced(sf4, 1, 8) == {(4, 50): 1}
     assert traced(sf4, 2, 8) == {}
+
+
+def test_rows_path_names_its_kernel_inside_dp_fwd(monkeypatch):
+    """With the row-blocked forward switched on for the CPU, the joint
+    program's row-blocked ops sit under ``dp_fwd/dp_fwd_rows``."""
+    monkeypatch.setattr(O, "dp_rows_platforms", lambda K: ("cpu",))
+    factory = F._compiled_dp_ckpt_scenario_core
+    jax.clear_caches()
+    factory.cache_clear()
+    try:
+        sf = _joint_fleet(3, 4, 2.0, 24)
+        text = _lowered_hlo(monkeypatch, "_compiled_dp_ckpt_scenario_core",
+                            lambda: _joint_opt(sf, 5, 12))
+    finally:
+        # programs traced here take the switched dispatch: leave none
+        jax.clear_caches()
+        factory.cache_clear()
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/dp_fwd/dp_fwd_rows/" in p for p in paths)
+
+
+def test_dp_rows_traces_count_wide_frontiers():
+    """The row-blocked path is traced into each new J = 50 program, not
+    again when the program is reused, and never into a J = 6 one."""
+    def traced(sf, seed, chunk):
+        before = dict(O.DP_ROWS_TRACES)
+        _joint_opt(sf, seed, chunk)
+        return {k: v - before.get(k, 0)
+                for k, v in O.DP_ROWS_TRACES.items()
+                if v != before.get(k, 0)}
+
+    # shapes no other test of the suite runs: 6 sites, 36 slots, chunk 12
+    sf2, sf4 = _joint_fleet(6, 2, 1.0, 36), _joint_fleet(6, 4, 2.0, 36)
+    assert sf2.sets[0].J < O.DP_ROWS_MIN_K
+    assert traced(sf2, 1, 12) == {}
+    assert set(traced(sf4, 1, 12)) == {(50, 12)}
+    assert traced(sf4, 2, 12) == {}                 # new keys, same program
 
 
 def _host_events(log_dir: str):
