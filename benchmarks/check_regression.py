@@ -59,12 +59,6 @@ RATIO_KEYS = {
 # would fail clean PRs on runner noise.  scaling_vs_1dev — real multi-core
 # speedup, so it tracks the runner's physical cores and contention, not
 # the code; kernel_bench.check already gates it with cores-aware bars.
-# dp_pallas_vs_xla / prng_pallas_vs_xla — the hosting-kernel backend
-# ratios depend on the Pallas execution mode (interpret on CPU, compiled
-# on accelerators; the report's top-level ``backend`` key records which)
-# so a baseline from one mode would wrongly gate runs in the other;
-# kernel_bench.check gates them >1 on compiled backends only, and the
-# rows' absolute ``*_per_sec`` keys still ride the rate guard below.
 # async_vs_sync — the ingestion-overlap win needs a spare physical core
 # for the prefetch thread, so it tracks the runner's core count and load
 # like scaling_vs_1dev does; kernel_bench.check gates it >= 0.9 in-row

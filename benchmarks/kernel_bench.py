@@ -83,14 +83,6 @@ engine's throughput axes:
   ``gather=True`` global totals across legs is asserted in-row; the ratio
   is cores-dependent (two processes need two cores to overlap) so, like
   ``scaling_vs_1dev``, only the rates feed the regression gate.
-* ``dp_minplus_kernel`` / ``counter_prng_kernel`` — the hosting Pallas
-  kernels (``kernels.hosting``) vs their canonical XLA references, on the
-  exact chunk ops the fleet engine dispatches through ``dp_backend=`` /
-  ``prng_backend=``.  Each row asserts bit-equality in-row (the portable
-  claim), records both rates plus the speedup ratio, and labels the
-  ``backend`` ("pallas-interpret" on CPU — wall time there is NOT an
-  accelerator projection) and ``device_kind``; ``check()`` gates the
-  ratio only on a compiled backend.
 """
 from __future__ import annotations
 
@@ -730,113 +722,6 @@ def policy_fanout(B=64, T=2048, chunk=None, reps=3, seed=0):
     return row
 
 
-def _hosting_backend_env():
-    """(backend label, device kind) for the hosting-kernel rows.  On CPU
-    the only executable Pallas path is interpret mode — labelled
-    "pallas-interpret" so the perf gate and check() can tell the modes
-    apart (interpret wall time is NOT an accelerator projection; the
-    bit-identity assert is the portable part of the row)."""
-    from repro.kernels.utils import default_interpret
-    interpret = default_interpret()
-    return ("pallas-interpret" if interpret else "pallas",
-            jax.devices()[0].device_kind, interpret)
-
-
-def dp_minplus_kernel(B=8, K=8, chunk=2048, reps=5, seed=0):
-    """Fused DP min-plus kernel vs the canonical lax.scan reference on one
-    [B]-vmapped chunk relaxation (the exact op ``offline_opt_fleet`` runs
-    per chunk per instance).  Bit-equality of (J', argmin table) is
-    asserted in-row; both rates are recorded and the ratio is gated in
-    ``check()`` only on a compiled (non-interpret) backend."""
-    from repro.core.policies.offline_opt import (dp_fetch_matrix,
-                                                 dp_frontier0, dp_fwd_chunk)
-    backend, device_kind, interpret = _hosting_backend_env()
-
-    rng = np.random.default_rng(seed)
-    lv32 = jnp.asarray(np.sort(rng.random((B, K)), axis=1).astype(np.float32))
-    fetch = jax.vmap(dp_fetch_matrix)(
-        jnp.asarray(rng.uniform(2, 8, B).astype(np.float32)), lv32)
-    kmask = jnp.asarray(rng.integers(2, K + 1, B))[:, None] > jnp.arange(K)
-    cck = jnp.asarray(rng.uniform(0.1, 2.0, (B, chunk)).astype(np.float32))
-    sck = jnp.asarray(rng.uniform(0, 3.0, (B, chunk, K)).astype(np.float32))
-    T_len = jnp.asarray(rng.integers(chunk // 2, chunk + 1, B), jnp.int32)
-    J = jnp.broadcast_to(dp_frontier0(K), (B, K))
-    tids = jnp.arange(chunk, dtype=jnp.int32)
-
-    def make(bk):
-        fn = jax.jit(jax.vmap(
-            lambda j, c, s, lv, km, f, tl: dp_fwd_chunk(
-                j, tids, c, s, lv, km, f, tl, bk),
-            in_axes=(0, 0, 0, 0, 0, 0, 0)))
-        return lambda: fn(J, cck, sck, lv32, kmask, fetch, T_len)
-
-    xla, pallas = make("xla"), make("pallas")
-    Jx, ax = jax.tree_util.tree_map(np.asarray, xla())
-    Jp, ap = jax.tree_util.tree_map(np.asarray, pallas())
-    identical = np.array_equal(Jx, Jp) and np.array_equal(ax, ap)
-    assert identical
-
-    def clock(fn):
-        fn()[0].block_until_ready()
-        t0 = time.time()
-        for _ in range(reps):
-            fn()[0].block_until_ready()
-        return (time.time() - t0) / reps
-
-    xla_s, pallas_s = clock(xla), clock(pallas)
-    slots = B * chunk
-    return {
-        "name": "dp_minplus_kernel",
-        "B": B, "K": K, "chunk": chunk,
-        "backend": backend, "device_kind": device_kind,
-        "identical_bits": bool(identical),
-        "xla_dp_slots_instances_per_sec": slots / xla_s,
-        "pallas_dp_slots_instances_per_sec": slots / pallas_s,
-        "dp_pallas_vs_xla": xla_s / pallas_s,
-    }
-
-
-def counter_prng_kernel(B=8, chunk=65536, reps=5, seed=0):
-    """Fused threefry counter-PRNG kernel vs the vmapped ``jax.random``
-    fold/salt/uniform chain (the exact ``slot_uniform`` op the hot streams
-    draw through).  Bit-equality asserted in-row; ratio gated only on a
-    compiled backend, like the DP row."""
-    from repro.core.scenarios.base import slot_uniform
-    backend, device_kind, interpret = _hosting_backend_env()
-
-    keys = jax.random.split(jax.random.PRNGKey(seed), B)
-    tids = jnp.arange(chunk, dtype=jnp.int32)
-    salt = 1
-
-    xla = jax.jit(jax.vmap(lambda k: slot_uniform(k, tids, salt)))
-    pallas = jax.jit(lambda ks: ops.counter_uniforms(ks, tids, salt=salt,
-                                                     interpret=interpret))
-    ux = np.asarray(xla(keys))
-    up = np.asarray(pallas(jnp.asarray(keys, jnp.uint32)))
-    identical = np.array_equal(ux, up)
-    assert identical
-
-    def clock(fn, arg):
-        fn(arg).block_until_ready()
-        t0 = time.time()
-        for _ in range(reps):
-            fn(arg).block_until_ready()
-        return (time.time() - t0) / reps
-
-    xla_s = clock(xla, keys)
-    pallas_s = clock(pallas, jnp.asarray(keys, jnp.uint32))
-    draws = B * chunk
-    return {
-        "name": "counter_prng_kernel",
-        "B": B, "chunk": chunk,
-        "backend": backend, "device_kind": device_kind,
-        "identical_bits": bool(identical),
-        "xla_prng_draws_per_sec": draws / xla_s,
-        "pallas_prng_draws_per_sec": draws / pallas_s,
-        "prng_pallas_vs_xla": xla_s / pallas_s,
-    }
-
-
 def multi_service(B=2, N=2, T=2048, chunk=1024, reps=3, seed=0):
     """Multi-service axis row (``core.services``): per-service lane-engine
     throughput at B instances x N services, with the service-axis
@@ -977,9 +862,6 @@ def run(T=4096):
                      "multihost_scaling_vs_1proc": None,
                      "single_process_slots_instances_per_sec": None,
                      "multi_process_slots_instances_per_sec": None})
-    # hosting-kernel backend rows: sizes track T so --fast stays fast
-    rows.append(dp_minplus_kernel(chunk=min(2048, T // 2)))
-    rows.append(counter_prng_kernel(chunk=min(65536, 16 * T)))
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (1, 256, 4, 64), jnp.float32)
     k = jax.random.normal(ks[1], (1, 256, 2, 64), jnp.float32)
@@ -1122,26 +1004,6 @@ def check(rows, cores=None):
     ok = ok and len(ms) == 1
     ok = ok and all(r["identical_bits"] and r["slots_instances_per_sec"] > 0
                     and r["joint_dp_seconds"] > 0 for r in ms)
-    # hosting-kernel backend rows: bit-identity is unconditional (it IS
-    # the backend-dispatch invariant); the speedup bar applies only to a
-    # compiled (non-interpret) backend — interpret mode re-traces the
-    # kernel body through the HLO interpreter and is expected to LOSE to
-    # XLA on CPU, which is why "xla" stays the default backend there.
-    dpk = [r for r in rows if r["name"] == "dp_minplus_kernel"]
-    prk = [r for r in rows if r["name"] == "counter_prng_kernel"]
-    ok = ok and len(dpk) == 1 and len(prk) == 1
-    for r in dpk:
-        ok = ok and r["identical_bits"]
-        ok = ok and r["xla_dp_slots_instances_per_sec"] > 0
-        ok = ok and r["pallas_dp_slots_instances_per_sec"] > 0
-        if not r["backend"].endswith("-interpret"):
-            ok = ok and r["dp_pallas_vs_xla"] > 1.0
-    for r in prk:
-        ok = ok and r["identical_bits"]
-        ok = ok and r["xla_prng_draws_per_sec"] > 0
-        ok = ok and r["pallas_prng_draws_per_sec"] > 0
-        if not r["backend"].endswith("-interpret"):
-            ok = ok and r["prng_pallas_vs_xla"] > 1.0
     return ok
 
 

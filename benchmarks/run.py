@@ -42,12 +42,10 @@ leg runs in full mode only).  ``multi_service`` adds ``n_services`` /
 ``joint_states`` / ``joint_dp_seconds`` (B x N per-service fleet lanes
 plus the capacity-respecting joint DP; the N=1 bitwise identity and
 joint-DP-vs-oracle claims are asserted in-row and folded into its
-``identical_bits``).  The hosting-kernel
-backend rows (``dp_minplus_kernel`` / ``counter_prng_kernel``) add their
-``*_pallas_vs_xla`` ratios, and the report itself gains top-level
-``backend`` / ``device_kind`` keys (additive, still schema 1) recording
-which Pallas mode the hosting rows measured ("pallas-interpret" on CPU)
-and ``jax.devices()[0].device_kind`` — so baselines from different
+``identical_bits``).  The report itself carries top-level ``backend`` /
+``device_kind`` keys (additive, still schema 1) recording which Pallas
+mode the kernel rows ran in ("pallas-interpret" on CPU) and
+``jax.devices()[0].device_kind`` — so baselines from different
 machines/modes are distinguishable.  ``multihost_scaling`` adds its
 2-process-vs-1 rates and ``multihost_scaling_vs_1proc`` ratio, and the
 report gains top-level ``process_count`` / ``host_count`` /
@@ -103,9 +101,15 @@ def main() -> None:
     import jax
 
     from repro.compile_cache import enable_compile_cache
+    from repro.kernels.utils import default_interpret
     enable_compile_cache()
     report = {"schema_version": 1, "fast": fast, "modules": [],
               "throughput": {},
+              # the Pallas mode the kernel rows (flash / SSD) ran in and
+              # the device (additive, schema stays 1)
+              "backend": ("pallas-interpret" if default_interpret()
+                          else "pallas"),
+              "device_kind": jax.devices()[0].device_kind,
               # JAX process topology of THIS benchmark process (additive,
               # schema stays 1).  Single-process on CI — the
               # multihost_scaling row's cluster legs are subprocesses —
@@ -236,31 +240,6 @@ def main() -> None:
                     "materialize_seconds": r.get("materialize_seconds"),
                     "B": r.get("B"), "T": r.get("T"),
                 }
-            if isinstance(r, dict) and "dp_pallas_vs_xla" in r:
-                report["throughput"][r.get("name", name)] = {
-                    "xla_dp_slots_instances_per_sec":
-                        r.get("xla_dp_slots_instances_per_sec"),
-                    "pallas_dp_slots_instances_per_sec":
-                        r.get("pallas_dp_slots_instances_per_sec"),
-                    "dp_pallas_vs_xla": r["dp_pallas_vs_xla"],
-                    "identical_bits": r.get("identical_bits"),
-                    "B": r.get("B"), "K": r.get("K"),
-                    "chunk": r.get("chunk"),
-                }
-                report["backend"] = r.get("backend")
-                report["device_kind"] = r.get("device_kind")
-            if isinstance(r, dict) and "prng_pallas_vs_xla" in r:
-                report["throughput"][r.get("name", name)] = {
-                    "xla_prng_draws_per_sec":
-                        r.get("xla_prng_draws_per_sec"),
-                    "pallas_prng_draws_per_sec":
-                        r.get("pallas_prng_draws_per_sec"),
-                    "prng_pallas_vs_xla": r["prng_pallas_vs_xla"],
-                    "identical_bits": r.get("identical_bits"),
-                    "B": r.get("B"), "chunk": r.get("chunk"),
-                }
-                report["backend"] = r.get("backend")
-                report["device_kind"] = r.get("device_kind")
         report["modules"].append({"name": name, "status": status,
                                   "seconds": round(dt, 2),
                                   "n_rows": len(rows)})

@@ -12,7 +12,7 @@ checkout load those programs instead:
 
 Either way the minimum compile time for an entry to be written drops to 0,
 so the engine's many sub-second programs are cached too.  Call it once,
-before the first compile (``chip_smoke.py`` and ``benchmarks/run.py`` do).
+before the first compile (``benchmarks/run.py`` does).
 """
 from __future__ import annotations
 

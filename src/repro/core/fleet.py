@@ -147,7 +147,7 @@ sample path in a single generation pass.  Conventions:
     in-carry reduction order, per-lane ``freeze_invalid`` — which is why
     ``policies=[p]`` fan-out == standalone ``run_fleet(p)`` and lane ``p``
     of a fan-out == its standalone restricted run hold *bitwise*, under
-    every mesh x chunking x streaming x ``n_seeds`` x backend config
+    every mesh x chunking x streaming x ``n_seeds`` config
     (tests/test_policy_fanout.py);
   * ``with_opt_forward=True`` threads one DP frontier per lane (the
     lane's own lv/mask, ``dp_fwd_chunk`` — the exact chunk kernel every
@@ -221,13 +221,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.costs import HostingCosts, HostingGrid, default_float_dtype
 from repro.core.ingest import slab_feed
 from repro.core.policies.base import PolicyFns, PolicyLane, as_policy_lanes
-from repro.core.policies.offline_opt import (DP_BACKENDS, dp_backtrack,
+from repro.core.policies.offline_opt import (dp_backtrack,
                                              dp_backtrack_chunk,
                                              dp_fetch_matrix, dp_frontier0,
                                              dp_fwd_chunk, dp_fwd_frontier)
-from repro.core.scenarios.base import PRNG_BACKENDS, Scenario, chunk_geometry
-from repro.core.scenarios.combinators import (replicate_seeds,
-                                              with_prng_backend)
+from repro.core.scenarios.base import Scenario, chunk_geometry
+from repro.core.scenarios.combinators import replicate_seeds
 from repro.core.simulator import (SimResult, sim_acc0, sim_chunk_core,
                                   sim_chunk_lanes, schedule_chunk_core)
 from repro.sharding.context import shard_ctx
@@ -1032,9 +1031,8 @@ def _lane_dp_grid(lanes):
     return tuple(out)
 
 
-def _fanout_chunk(lane_fns, lane_own, include_final_fetch, with_opt,
-                  dp_backend, lanes, dp_grid, T_len, t0, sims, Js,
-                  x, c, svc, side):
+def _fanout_chunk(lane_fns, lane_own, include_final_fetch, with_opt, lanes,
+                  dp_grid, T_len, t0, sims, Js, x, c, svc, side):
     """Advance every lane (and optionally every DP frontier) over ONE
     shared slab — the body every fan-out driver shares.  Returns
     (sims', Js', per-lane r chunks)."""
@@ -1049,15 +1047,14 @@ def _fanout_chunk(lane_fns, lane_own, include_final_fetch, with_opt,
         tids = t0 + jnp.arange(x.shape[-1], dtype=jnp.int32)
         Js = tuple(
             dp_fwd_chunk(J, tids, c, svck, lv32, kmask, fetch_mat,
-                         T_len, dp_backend)[0]
+                         T_len)[0]
             for J, (lv32, fetch_mat, kmask), svck in zip(Js, dp_grid, svcs))
     return sims, Js, rs
 
 
 def _make_fanout_instance_core(lane_fns, lane_own, include_final_fetch: bool,
                                n_chunks: int, has_svc: bool, has_side: bool,
-                               collect_trace: bool, with_opt: bool,
-                               dp_backend: str):
+                               collect_trace: bool, with_opt: bool):
     """Whole-horizon fan-out core for ONE instance, obs-backed.
     Args: (lanes, T_len, x, c[, svc][, side])."""
     n_lanes = len(lane_fns)
@@ -1077,9 +1074,8 @@ def _make_fanout_instance_core(lane_fns, lane_own, include_final_fetch: bool,
             if sdck is None:
                 sdck = jnp.zeros(xck.shape, jnp.int32)
             sims, Js, rs = _fanout_chunk(
-                lane_fns, lane_own, include_final_fetch, with_opt,
-                dp_backend, lanes, dp_grid, T_len, t0, sims, Js,
-                xck, cck, sck, sdck)
+                lane_fns, lane_own, include_final_fetch, with_opt, lanes,
+                dp_grid, T_len, t0, sims, Js, xck, cck, sck, sdck)
             carry = (sims, Js) if with_opt else sims
             return carry, (rs if collect_trace else None)
 
@@ -1098,8 +1094,7 @@ def _make_fanout_instance_core(lane_fns, lane_own, include_final_fetch: bool,
 
 def _make_fanout_scenario_core(lane_fns, lane_own, sc_init, sc_chunk,
                                include_final_fetch: bool, n_chunks: int,
-                               collect_trace: bool, with_opt: bool,
-                               dp_backend: str):
+                               collect_trace: bool, with_opt: bool):
     """Fused-generation fan-out core for ONE instance: the scenario's
     ``chunk_fn`` emits each [chunk] slab exactly once inside the scan and
     every lane consumes it.  Args: (lanes, sparams, T_len, tids_all)."""
@@ -1120,9 +1115,8 @@ def _make_fanout_scenario_core(lane_fns, lane_own, sc_init, sc_chunk,
             side = (slab.side if slab.side is not None
                     else jnp.zeros(slab.x.shape, jnp.int32))
             sims, Js, rs = _fanout_chunk(
-                lane_fns, lane_own, include_final_fetch, with_opt,
-                dp_backend, lanes, dp_grid, T_len, t0, sims, Js,
-                slab.x, slab.c, slab.svc, side)
+                lane_fns, lane_own, include_final_fetch, with_opt, lanes,
+                dp_grid, T_len, t0, sims, Js, slab.x, slab.c, slab.svc, side)
             carry = (gen_state, sims) + ((Js,) if with_opt else ())
             return carry, (rs if collect_trace else None)
 
@@ -1142,11 +1136,10 @@ def _make_fanout_scenario_core(lane_fns, lane_own, sc_init, sc_chunk,
 @functools.lru_cache(maxsize=32)
 def _compiled_fanout_core(lane_fns, lane_own, include_final_fetch: bool,
                           n_chunks: int, has_svc: bool, has_side: bool,
-                          collect_trace: bool, with_opt: bool,
-                          dp_backend: str, mesh: Mesh):
+                          collect_trace: bool, with_opt: bool, mesh: Mesh):
     core = _make_fanout_instance_core(lane_fns, lane_own, include_final_fetch,
                                       n_chunks, has_svc, has_side,
-                                      collect_trace, with_opt, dp_backend)
+                                      collect_trace, with_opt)
     n_lanes = len(lane_fns)
     spec = P(FLEET_AXIS)
     n_args = 4 + int(has_svc) + int(has_side)
@@ -1161,10 +1154,10 @@ def _compiled_fanout_core(lane_fns, lane_own, include_final_fetch: bool,
 def _compiled_fanout_scenario_core(lane_fns, lane_own, sc_init, sc_chunk,
                                    include_final_fetch: bool, n_chunks: int,
                                    collect_trace: bool, with_opt: bool,
-                                   dp_backend: str, mesh: Mesh):
+                                   mesh: Mesh):
     core = _make_fanout_scenario_core(lane_fns, lane_own, sc_init, sc_chunk,
                                       include_final_fetch, n_chunks,
-                                      collect_trace, with_opt, dp_backend)
+                                      collect_trace, with_opt)
     n_lanes = len(lane_fns)
     spec = P(FLEET_AXIS)
     n_out = n_lanes * (2 + int(collect_trace) + int(with_opt))
@@ -1178,7 +1171,7 @@ def _compiled_fanout_scenario_core(lane_fns, lane_own, sc_init, sc_chunk,
 def _compiled_fanout_stream_step(lane_fns, lane_own,
                                  include_final_fetch: bool, has_svc: bool,
                                  has_side: bool, collect_trace: bool,
-                                 with_opt: bool, dp_backend: str, mesh: Mesh,
+                                 with_opt: bool, mesh: Mesh,
                                  donate: bool = False):
     """One fan-out slab step for the host streaming loop: the shared
     [B, chunk] slab in, every lane's (state, acc) — and DP frontier with
@@ -1194,7 +1187,7 @@ def _compiled_fanout_stream_step(lane_fns, lane_own,
         Js = carry[1] if with_opt else None
         dp_grid = _lane_dp_grid(lanes) if with_opt else None
         sims, Js, rs = _fanout_chunk(
-            lane_fns, lane_own, include_final_fetch, with_opt, dp_backend,
+            lane_fns, lane_own, include_final_fetch, with_opt,
             lanes, dp_grid, T_len, t0, sims, Js, xck, cck, sck, sdck)
         carry = (sims, Js) if with_opt else (sims,)
         return (carry, rs) if collect_trace else carry
@@ -1215,7 +1208,7 @@ def _compiled_fanout_scenario_stream_step(lane_fns, lane_own, sc_init,
                                           sc_chunk,
                                           include_final_fetch: bool,
                                           chunk: int, collect_trace: bool,
-                                          with_opt: bool, dp_backend: str,
+                                          with_opt: bool,
                                           mesh: Mesh, donate: bool = False):
     """One fused-generation fan-out slab step: the host ships one scalar
     offset per chunk, the generator runs once, every lane consumes its
@@ -1231,7 +1224,7 @@ def _compiled_fanout_scenario_stream_step(lane_fns, lane_own, sc_init,
         side = (slab.side if slab.side is not None
                 else jnp.zeros(slab.x.shape, jnp.int32))
         sims, Js, rs = _fanout_chunk(
-            lane_fns, lane_own, include_final_fetch, with_opt, dp_backend,
+            lane_fns, lane_own, include_final_fetch, with_opt,
             lanes, dp_grid, T_len, t0, sims, Js, slab.x, slab.c, slab.svc,
             side)
         carry = (gen_state, sims) + ((Js,) if with_opt else ())
@@ -1379,9 +1372,7 @@ def run_fleet(policy, fleet: FleetBatch, *,
               stream: bool = False, collect_trace: bool = True,
               n_seeds: Optional[int] = None,
               antithetic: bool = False,
-              prng_backend: str = "xla",
               with_opt_forward: bool = False,
-              dp_backend: str = "xla",
               async_ingest: bool = False,
               gather: bool = False) -> FleetResult:
     """Simulate a fleet: sharded over devices, chunked/streamed over time.
@@ -1425,10 +1416,6 @@ def run_fleet(policy, fleet: FleetBatch, *,
         flip-capable streams (``scenarios.replicate_seeds(...,
         antithetic=True)``) — same estimator mean, tighter ``mc_summary``
         CIs on monotone statistics.  Requires an even ``n_seeds``.
-      prng_backend: kernel backend for the scenario's counter-keyed
-        uniforms ("xla" default — the canonical reference; "pallas" fuses
-        the fold/salt/uniform chain via ``scenarios.with_prng_backend``).
-        Bit-identical observations either way (requires ``scenario=``).
       with_opt_forward: co-execute the offline DP's [K] entry frontier per
         policy lane against the same shared stream (the cost-only forward
         pass — ``dp_fwd_chunk``, the offline drivers' own chunk kernel)
@@ -1436,9 +1423,6 @@ def run_fleet(policy, fleet: FleetBatch, *,
         ``offline_opt_fleet(..., checkpointed=True,
         collect_schedule=False).cost`` of the lane's fleet.  A plain
         ``PolicyFns`` policy is treated as a single-lane fan-out.
-      dp_backend: min-plus engine for the co-executed DP ("xla" default /
-        "pallas"), exactly as in ``offline_opt_fleet``; only consulted
-        with ``with_opt_forward=True``.
       async_ingest: with ``stream=True`` on an obs-backed fleet, prepare
         slab n+1 (host slicing + device put) on a background prefetch
         thread while the device executes slab n
@@ -1452,10 +1436,10 @@ def run_fleet(policy, fleet: FleetBatch, *,
         single-process meshes.  See "Multi-host fleets" above.
 
     Every configuration (any mesh size x any chunking x any driver x fused
-    or materialized generation — and any ``prng_backend``) returns
-    bit-identical results; see tests/test_fleet_engine.py,
-    tests/test_scenarios.py, tests/test_mc_driver.py,
-    tests/test_backend_dispatch.py and tests/test_policy_fanout.py.
+    or materialized generation) returns bit-identical results; see
+    tests/test_fleet_engine.py, tests/test_scenarios.py,
+    tests/test_mc_driver.py, tests/test_driver_configs.py and
+    tests/test_policy_fanout.py.
     """
     lanes = as_policy_lanes(policy)
     if lanes is None and with_opt_forward:
@@ -1465,18 +1449,14 @@ def run_fleet(policy, fleet: FleetBatch, *,
             lanes, fleet, scenario=scenario, mesh=mesh,
             chunk_size=chunk_size, include_final_fetch=include_final_fetch,
             stream=stream, collect_trace=collect_trace, n_seeds=n_seeds,
-            antithetic=antithetic, prng_backend=prng_backend,
-            dp_backend=dp_backend, with_opt=with_opt_forward,
+            antithetic=antithetic, with_opt=with_opt_forward,
             async_ingest=async_ingest, gather=gather)
     if stream and chunk_size is None:
         raise ValueError("stream=True requires chunk_size")
     if async_ingest and not stream:
         raise ValueError("async_ingest=True requires stream=True (only the "
                          "host-driven driver ships slabs to prefetch)")
-    _check_backends(dp_backend, prng_backend, scenario)
     fleet, scenario, S = _replicate_mc(fleet, scenario, n_seeds, antithetic)
-    if scenario is not None:
-        scenario = with_prng_backend(scenario, prng_backend)
     policy = _replicate_policy(policy, S)
     B, T_max = fleet.B, fleet.T_max
     mesh, padded, n_chunks, T_pad = _prepare_fleet(fleet, mesh, chunk_size)
@@ -1529,8 +1509,8 @@ def run_fleet(policy, fleet: FleetBatch, *,
 
 def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
                       chunk_size, include_final_fetch, stream, collect_trace,
-                      n_seeds, antithetic, prng_backend, dp_backend,
-                      with_opt, async_ingest, gather) -> FleetResult:
+                      n_seeds, antithetic, with_opt, async_ingest,
+                      gather) -> FleetResult:
     """Driver of the policy fan-out axis (see the module docstring): ONE
     generation pass, P policy lanes (+ optional per-lane DP frontiers),
     chunked or streamed, returning a policy-major ``FleetResult``."""
@@ -1539,12 +1519,9 @@ def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
     if async_ingest and not stream:
         raise ValueError("async_ingest=True requires stream=True (only the "
                          "host-driven driver ships slabs to prefetch)")
-    _check_backends(dp_backend, prng_backend, scenario)
     has_svc = None if scenario is not None else fleet.svc is not None
     _check_lanes(lanes, fleet, has_svc)
     fleet, scenario, S = _replicate_mc(fleet, scenario, n_seeds, antithetic)
-    if scenario is not None:
-        scenario = with_prng_backend(scenario, prng_backend)
     B, T_max = fleet.B, fleet.T_max
     mesh, padded, n_chunks, T_pad = _prepare_fleet(fleet, mesh, chunk_size)
     lane_args = _lane_arrays(lanes, padded, S, mesh)
@@ -1559,12 +1536,10 @@ def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
             return _run_fleet_fanout_streamed(
                 lanes, lane_fns, lane_own, lane_args, scenario, padded,
                 sparams, mesh, n_chunks, T_pad, include_final_fetch,
-                collect_trace, with_opt, dp_backend, B, T_max, fleet.T, S,
-                False, gather)
+                collect_trace, with_opt, B, T_max, fleet.T, S, False, gather)
         core = _compiled_fanout_scenario_core(
             lane_fns, lane_own, scenario.init_fn, scenario.chunk_fn,
-            include_final_fetch, n_chunks, collect_trace, with_opt,
-            dp_backend, mesh)
+            include_final_fetch, n_chunks, collect_trace, with_opt, mesh)
         tids_all = _dev_replicated(mesh, np.arange(T_pad, dtype=np.int32))
         outs = _dispatch(mesh, core, lane_args, sparams,
                          _dev_rows(mesh, padded.T), tids_all)
@@ -1574,11 +1549,10 @@ def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
             return _run_fleet_fanout_streamed(
                 lanes, lane_fns, lane_own, lane_args, None, padded, None,
                 mesh, n_chunks, T_pad, include_final_fetch, collect_trace,
-                with_opt, dp_backend, B, T_max, fleet.T, S, async_ingest,
-                gather)
+                with_opt, B, T_max, fleet.T, S, async_ingest, gather)
         core = _compiled_fanout_core(
             lane_fns, lane_own, include_final_fetch, n_chunks, has_svc,
-            has_side, collect_trace, with_opt, dp_backend, mesh)
+            has_side, collect_trace, with_opt, mesh)
         args = (lane_args, _dev_rows(mesh, padded.T),
                 _dev_rows(mesh, padded.x), _dev_rows(mesh, padded.c))
         if has_svc:
@@ -1601,7 +1575,7 @@ def _run_fleet_fanout(lanes, fleet: FleetBatch, *, scenario, mesh,
 def _run_fleet_fanout_streamed(lanes, lane_fns, lane_own, lane_args,
                                scenario, padded, sparams, mesh, n_chunks,
                                T_pad, include_final_fetch, collect_trace,
-                               with_opt, dp_backend, B, T_max, T_orig,
+                               with_opt, B, T_max, T_orig,
                                n_seeds, async_ingest, gather) -> FleetResult:
     """Host-driven fan-out streaming: a thin loop over the persistent
     fan-out ``FleetStepper`` (same donated-carry, zero-retrace contract as
@@ -1612,7 +1586,7 @@ def _run_fleet_fanout_streamed(lanes, lane_fns, lane_own, lane_args,
     stepper = _make_fanout_stepper(lanes, lane_fns, lane_own, lane_args,
                                    scenario, padded, sparams, mesh, chunk,
                                    include_final_fetch, collect_trace,
-                                   with_opt, dp_backend, True, has_svc,
+                                   with_opt, True, has_svc,
                                    has_side, B, T_max, T_orig, n_seeds)
     if scenario is None:
         make_slab = _obs_slab_builder(padded, chunk, mesh, with_side=True)
@@ -1928,7 +1902,7 @@ def _make_sim_stepper(policy, scenario, padded, params, sparams, lv, g, M,
 
 def _make_fanout_stepper(lanes, lane_fns, lane_own, lane_args, scenario,
                          padded, sparams, mesh, chunk, include_final_fetch,
-                         collect_trace, with_opt, dp_backend, donate,
+                         collect_trace, with_opt, donate,
                          has_svc, has_side, B, T_max, T_orig, n_seeds):
     """Build a fan-out ``FleetStepper``: the compiled multi-lane slab step,
     the tuple-of-lane-carries (+ per-lane DP frontiers with ``with_opt``),
@@ -1952,7 +1926,7 @@ def _make_fanout_stepper(lanes, lane_fns, lane_own, lane_args, scenario,
     if scenario is not None:
         step = _compiled_fanout_scenario_stream_step(
             lane_fns, lane_own, scenario.init_fn, scenario.chunk_fn,
-            include_final_fetch, chunk, collect_trace, with_opt, dp_backend,
+            include_final_fetch, chunk, collect_trace, with_opt,
             mesh, donate)
         carry = (_vmap_init(scenario.init_fn, sparams, mesh), sims0) + Js0
 
@@ -1961,7 +1935,7 @@ def _make_fanout_stepper(lanes, lane_fns, lane_own, lane_args, scenario,
     else:
         step = _compiled_fanout_stream_step(
             lane_fns, lane_own, include_final_fetch, has_svc, has_side,
-            collect_trace, with_opt, dp_backend, mesh, donate)
+            collect_trace, with_opt, mesh, donate)
         carry = (sims0,) + Js0
 
         def call(carry, t0, slabs):
@@ -1984,9 +1958,7 @@ def fleet_stepper(policy, fleet: FleetBatch, *,
                   include_final_fetch: bool = True,
                   collect_trace: bool = True,
                   n_seeds: Optional[int] = None, antithetic: bool = False,
-                  prng_backend: str = "xla",
                   with_opt_forward: bool = False,
-                  dp_backend: str = "xla",
                   donate: bool = True) -> FleetStepper:
     """Long-lived stepping API for live fleets: pre-compile once, then
     ``step()`` the whole fleet one [B, chunk_size] telemetry slab at a
@@ -2011,8 +1983,8 @@ def fleet_stepper(policy, fleet: FleetBatch, *,
     past each instance's own T_i stay exact no-ops.
 
     Scenario-fused mode: ``step()`` takes no arguments; the generator
-    advances on device (``n_seeds``/``antithetic``/``prng_backend``
-    compose exactly as in ``run_fleet``).
+    advances on device (``n_seeds``/``antithetic`` compose exactly as in
+    ``run_fleet``).
 
     N ``step()`` calls are bit-identical to one ``run_fleet`` call over
     the same observations — the engine invariant, proven in
@@ -2023,7 +1995,6 @@ def fleet_stepper(policy, fleet: FleetBatch, *,
     lanes = as_policy_lanes(policy)
     if lanes is None and with_opt_forward:
         lanes = (PolicyLane(policy),)
-    _check_backends(dp_backend, prng_backend, scenario)
     if scenario is None and n_seeds is not None:
         raise ValueError("n_seeds= needs scenario= (as in run_fleet)")
     if lanes is not None:
@@ -2032,7 +2003,6 @@ def fleet_stepper(policy, fleet: FleetBatch, *,
     fleet, scenario, S = _replicate_mc(fleet, scenario, n_seeds, antithetic)
     if scenario is not None:
         _check_scenario(scenario, fleet)
-        scenario = with_prng_backend(scenario, prng_backend)
     B, T_max = fleet.B, fleet.T_max
     mesh, padded, _, _ = _prepare_fleet(fleet, mesh, int(chunk_size))
     sparams = (None if scenario is None
@@ -2046,9 +2016,8 @@ def fleet_stepper(policy, fleet: FleetBatch, *,
         return _make_fanout_stepper(lanes, lane_fns, lane_own, lane_args,
                                     scenario, padded, sparams, mesh,
                                     int(chunk_size), include_final_fetch,
-                                    collect_trace, with_opt_forward,
-                                    dp_backend, donate, has_svc, has_side,
-                                    B, T_max, fleet.T, S)
+                                    collect_trace, with_opt_forward, donate,
+                                    has_svc, has_side, B, T_max, fleet.T, S)
     policy = _replicate_policy(policy, S)
     params, lv, g, M = _policy_arrays(policy, padded, padded.B, mesh)
     return _make_sim_stepper(policy, scenario, padded, params, sparams, lv,
@@ -2105,26 +2074,9 @@ def _run_fleet_scenario_streamed(policy, scenario, padded, params, sparams,
 # Offline DP on a fleet: chunked forward recursion, frozen past T_i.
 # The chunk-level recursion itself (``dp_fwd_chunk`` / ``dp_backtrack*``)
 # lives in ``policies.offline_opt`` — ONE copy shared by every driver here.
-# ``dp_backend`` threads through every core factory into that one call
-# site (and into the compile-cache keys, so backends never share a trace).
 # ----------------------------------------------------------------------
 
-def _check_backends(dp_backend: str, prng_backend: str,
-                    scenario=None) -> None:
-    """Validate the engine entry points' backend arguments up front."""
-    if dp_backend not in DP_BACKENDS:
-        raise ValueError(f"dp_backend must be one of {DP_BACKENDS}, "
-                         f"got {dp_backend!r}")
-    if prng_backend not in PRNG_BACKENDS:
-        raise ValueError(f"prng_backend must be one of {PRNG_BACKENDS}, "
-                         f"got {prng_backend!r}")
-    if prng_backend != "xla" and scenario is None:
-        raise ValueError("prng_backend= needs scenario=: materialized "
-                         "observations draw no slot uniforms to reroute")
-
-
-def _make_dp_instance_core(n_chunks: int, has_svc: bool,
-                           dp_backend: str = "xla"):
+def _make_dp_instance_core(n_chunks: int, has_svc: bool):
     """Forward DP + reverse backtrack for ONE instance, chunk-capable.
 
     Matches ``offline_opt._dp_core`` op-for-op on valid slots; invalid slots
@@ -2147,7 +2099,7 @@ def _make_dp_instance_core(n_chunks: int, has_svc: bool,
                 sck = _model1_svc(xck, g)
             tids = t0 + jnp.arange(xck.shape[-1], dtype=jnp.int32)
             return dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat,
-                                T_len, dp_backend)
+                                T_len)
 
         J_T, args = _chunked_drive(fwd_chunk, dp_frontier0(K), n_chunks,
                                    (x, c, svc))
@@ -2156,8 +2108,7 @@ def _make_dp_instance_core(n_chunks: int, has_svc: bool,
     return core
 
 
-def _make_dp_scenario_core(sc_init, sc_chunk, n_chunks: int,
-                           dp_backend: str = "xla"):
+def _make_dp_scenario_core(sc_init, sc_chunk, n_chunks: int):
     """Scenario-fused forward DP for ONE instance: slabs are generated
     inside the chunk scan (generator state in the carry next to J); the
     recursion itself is ``dp_fwd_chunk``, shared with the obs-backed core."""
@@ -2172,7 +2123,7 @@ def _make_dp_scenario_core(sc_init, sc_chunk, n_chunks: int,
             gen_state, slab = sc_chunk(sparams, gen_state, tids)
             sck = slab.svc if slab.svc is not None else _model1_svc(slab.x, g)
             J, args = dp_fwd_chunk(J, tids, slab.c, sck, lv32, kmask,
-                                   fetch_mat, T_len, dp_backend)
+                                   fetch_mat, T_len)
             return (gen_state, J), args
 
         carry0 = (sc_init(sparams), dp_frontier0(K))
@@ -2190,8 +2141,7 @@ def _make_dp_scenario_core(sc_init, sc_chunk, n_chunks: int,
 # ----------------------------------------------------------------------
 
 def _make_dp_ckpt_instance_core(n_chunks: int, has_svc: bool,
-                                collect_schedule: bool,
-                                dp_backend: str = "xla"):
+                                collect_schedule: bool):
     """Checkpointed DP for ONE instance, obs-backed.
 
     Pass 1 runs ``dp_fwd_frontier`` (``dp_fwd_chunk`` without the table)
@@ -2221,8 +2171,7 @@ def _make_dp_ckpt_instance_core(n_chunks: int, has_svc: bool,
             if sck is None:
                 sck = _model1_svc(xck, g)
             tids = t0 + jnp.arange(chunk, dtype=jnp.int32)
-            return relax(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
-                         dp_backend)
+            return relax(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len)
 
         def fwd(J, inp):
             t0, xck, cck, sck = inp
@@ -2247,8 +2196,7 @@ def _make_dp_ckpt_instance_core(n_chunks: int, has_svc: bool,
 
 
 def _make_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
-                                collect_schedule: bool,
-                                dp_backend: str = "xla"):
+                                collect_schedule: bool):
     """Checkpointed DP with fused generation: pass 1 additionally
     checkpoints the generator state at each chunk entry (small — recursion
     state only, the innovations are counter-keyed), so pass 2 regenerates
@@ -2268,7 +2216,7 @@ def _make_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
             gen2, slab = sc_chunk(sparams, gen_state, tids)
             sck = slab.svc if slab.svc is not None else _model1_svc(slab.x, g)
             return gen2, relax(J, tids, slab.c, sck, lv32, kmask,
-                               fetch_mat, T_len, dp_backend)
+                               fetch_mat, T_len)
 
         def fwd(carry, tids):
             gen_state, J = carry
@@ -2295,9 +2243,8 @@ def _make_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
 
 
 @functools.lru_cache(maxsize=32)
-def _compiled_dp_core(n_chunks: int, has_svc: bool, mesh: Mesh,
-                      dp_backend: str = "xla"):
-    core = _make_dp_instance_core(n_chunks, has_svc, dp_backend)
+def _compiled_dp_core(n_chunks: int, has_svc: bool, mesh: Mesh):
+    core = _make_dp_instance_core(n_chunks, has_svc)
     spec = P(FLEET_AXIS)
     sharded = _fleet_map(jax.vmap(core), mesh=mesh,
                          in_specs=(spec,) * (7 + int(has_svc)),
@@ -2306,9 +2253,8 @@ def _compiled_dp_core(n_chunks: int, has_svc: bool, mesh: Mesh,
 
 
 @functools.lru_cache(maxsize=32)
-def _compiled_dp_scenario_core(sc_init, sc_chunk, n_chunks: int, mesh: Mesh,
-                               dp_backend: str = "xla"):
-    core = _make_dp_scenario_core(sc_init, sc_chunk, n_chunks, dp_backend)
+def _compiled_dp_scenario_core(sc_init, sc_chunk, n_chunks: int, mesh: Mesh):
+    core = _make_dp_scenario_core(sc_init, sc_chunk, n_chunks)
     spec = P(FLEET_AXIS)
     sharded = _fleet_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
                          mesh=mesh, in_specs=(spec,) * 6 + (P(),),
@@ -2318,10 +2264,8 @@ def _compiled_dp_scenario_core(sc_init, sc_chunk, n_chunks: int, mesh: Mesh,
 
 @functools.lru_cache(maxsize=32)
 def _compiled_dp_ckpt_core(n_chunks: int, has_svc: bool,
-                           collect_schedule: bool, mesh: Mesh,
-                           dp_backend: str = "xla"):
-    core = _make_dp_ckpt_instance_core(n_chunks, has_svc, collect_schedule,
-                                       dp_backend)
+                           collect_schedule: bool, mesh: Mesh):
+    core = _make_dp_ckpt_instance_core(n_chunks, has_svc, collect_schedule)
     spec = P(FLEET_AXIS)
     out_specs = (spec, spec) if collect_schedule else spec
     sharded = _fleet_map(jax.vmap(core), mesh=mesh,
@@ -2332,10 +2276,9 @@ def _compiled_dp_ckpt_core(n_chunks: int, has_svc: bool,
 
 @functools.lru_cache(maxsize=32)
 def _compiled_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
-                                    collect_schedule: bool, mesh: Mesh,
-                                    dp_backend: str = "xla"):
+                                    collect_schedule: bool, mesh: Mesh):
     core = _make_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks,
-                                       collect_schedule, dp_backend)
+                                       collect_schedule)
     spec = P(FLEET_AXIS)
     out_specs = (spec, spec) if collect_schedule else spec
     sharded = _fleet_map(jax.vmap(core, in_axes=(0, 0, 0, 0, 0, 0, None)),
@@ -2348,9 +2291,7 @@ def _compiled_dp_ckpt_scenario_core(sc_init, sc_chunk, n_chunks: int,
 # chunk at a time, so neither obs nor r_hist is ever device-resident whole.
 
 @functools.lru_cache(maxsize=32)
-def _compiled_dp_stream_fwd(has_svc: bool, mesh: Mesh,
-                            dp_backend: str = "xla",
-                            donate: bool = False):
+def _compiled_dp_stream_fwd(has_svc: bool, mesh: Mesh, donate: bool = False):
     """One forward slab of the value recursion: ``J -> J'``.
     ``donate=True`` donates the frontier and slab buffers — only legal for
     cost-only solves (``collect_schedule=True`` retains old frontiers as
@@ -2363,7 +2304,7 @@ def _compiled_dp_stream_fwd(has_svc: bool, mesh: Mesh,
         sck = opt[0] if has_svc else _model1_svc(xck, g)
         tids = t0 + jnp.arange(xck.shape[-1], dtype=jnp.int32)
         return dp_fwd_frontier(J, tids, cck, sck, lv32, kmask, fetch_mat,
-                               T_len, dp_backend)
+                               T_len)
 
     n_opt = int(has_svc)
     in_axes = (0, 0, 0, 0, 0, None, 0, 0, 0) + (0,) * n_opt
@@ -2376,8 +2317,7 @@ def _compiled_dp_stream_fwd(has_svc: bool, mesh: Mesh,
 
 
 @functools.lru_cache(maxsize=32)
-def _compiled_dp_stream_bwd(has_svc: bool, mesh: Mesh,
-                            dp_backend: str = "xla"):
+def _compiled_dp_stream_bwd(has_svc: bool, mesh: Mesh):
     """One backward slab: recompute the chunk's argmins from its checkpoint
     and backtrack through them — ``(J_ckpt, k) -> (k_entry, r_chunk)``."""
 
@@ -2387,7 +2327,7 @@ def _compiled_dp_stream_bwd(has_svc: bool, mesh: Mesh,
         sck = opt[0] if has_svc else _model1_svc(xck, g)
         tids = t0 + jnp.arange(xck.shape[-1], dtype=jnp.int32)
         _, args = dp_fwd_chunk(Jck, tids, cck, sck, lv32, kmask, fetch_mat,
-                               T_len, dp_backend)
+                               T_len)
         k0, rck = dp_backtrack_chunk(k, args)
         return k0, rck.astype(jnp.int32)
 
@@ -2402,7 +2342,7 @@ def _compiled_dp_stream_bwd(has_svc: bool, mesh: Mesh,
 
 @functools.lru_cache(maxsize=32)
 def _compiled_dp_scenario_stream_fwd(sc_init, sc_chunk, chunk: int,
-                                     mesh: Mesh, dp_backend: str = "xla",
+                                     mesh: Mesh,
                                      donate: bool = False):
     """One fused-generation forward slab: the host ships one scalar offset
     per chunk; ``(gen_state, J) -> (gen', J')``.  ``donate=True`` donates
@@ -2417,7 +2357,7 @@ def _compiled_dp_scenario_stream_fwd(sc_init, sc_chunk, chunk: int,
         gen2, slab = sc_chunk(sparams, gen_state, tids)
         sck = slab.svc if slab.svc is not None else _model1_svc(slab.x, g)
         return gen2, dp_fwd_frontier(J, tids, slab.c, sck, lv32, kmask,
-                                     fetch_mat, T_len, dp_backend)
+                                     fetch_mat, T_len)
 
     spec = P(FLEET_AXIS)
     sharded = _fleet_map(
@@ -2428,7 +2368,7 @@ def _compiled_dp_scenario_stream_fwd(sc_init, sc_chunk, chunk: int,
 
 @functools.lru_cache(maxsize=32)
 def _compiled_dp_scenario_stream_bwd(sc_init, sc_chunk, chunk: int,
-                                     mesh: Mesh, dp_backend: str = "xla"):
+                                     mesh: Mesh):
     """One fused-generation backward slab: regenerate the chunk from its
     generator-state checkpoint, recompute its argmins, backtrack."""
 
@@ -2439,7 +2379,7 @@ def _compiled_dp_scenario_stream_bwd(sc_init, sc_chunk, chunk: int,
         _, slab = sc_chunk(sparams, gen_ck, tids)
         sck = slab.svc if slab.svc is not None else _model1_svc(slab.x, g)
         _, args = dp_fwd_chunk(Jck, tids, slab.c, sck, lv32, kmask, fetch_mat,
-                               T_len, dp_backend)
+                               T_len)
         k0, rck = dp_backtrack_chunk(k, args)
         return k0, rck.astype(jnp.int32)
 
@@ -2460,8 +2400,7 @@ def _dp_grid_args(padded: FleetBatch, mesh):
 
 
 def _dp_scan_core_args(scenario, padded, mesh, n_chunks, T_pad,
-                       checkpointed: bool, collect_schedule: bool,
-                       dp_backend: str = "xla"):
+                       checkpointed: bool, collect_schedule: bool):
     """(compiled device-scan DP core, its args) for this config — shared by
     ``offline_opt_fleet`` and ``offline_dp_memory_stats`` so the probed
     program is exactly the executed one."""
@@ -2471,20 +2410,20 @@ def _dp_scan_core_args(scenario, padded, mesh, n_chunks, T_pad,
         if checkpointed:
             core = _compiled_dp_ckpt_scenario_core(
                 scenario.init_fn, scenario.chunk_fn, n_chunks,
-                collect_schedule, mesh, dp_backend)
+                collect_schedule, mesh)
         else:
             core = _compiled_dp_scenario_core(scenario.init_fn,
                                               scenario.chunk_fn, n_chunks,
-                                              mesh, dp_backend)
+                                              mesh)
         args = (sparams,) + grid_args + (
             _dev_replicated(mesh, np.arange(T_pad, dtype=np.int32)),)
     else:
         has_svc = padded.svc is not None
         if checkpointed:
             core = _compiled_dp_ckpt_core(n_chunks, has_svc, collect_schedule,
-                                          mesh, dp_backend)
+                                          mesh)
         else:
-            core = _compiled_dp_core(n_chunks, has_svc, mesh, dp_backend)
+            core = _compiled_dp_core(n_chunks, has_svc, mesh)
         args = grid_args + (_dev_rows(mesh, padded.x),
                             _dev_rows(mesh, padded.c))
         if has_svc:
@@ -2493,8 +2432,7 @@ def _dp_scan_core_args(scenario, padded, mesh, n_chunks, T_pad,
 
 
 def _dp_ckpt_streamed(scenario, padded, mesh, n_chunks, T_pad,
-                      collect_schedule: bool, dp_backend: str = "xla",
-                      async_ingest: bool = False):
+                      collect_schedule: bool, async_ingest: bool = False):
     """Host-driven checkpointed DP: forward loop collecting per-chunk
     frontier (+ generator-state) checkpoints in a device-resident list,
     then a backward loop replaying the chunks in reverse.  With a scenario
@@ -2518,10 +2456,10 @@ def _dp_ckpt_streamed(scenario, padded, mesh, n_chunks, T_pad,
         sparams = _dev_tree(mesh, _pad_params(scenario.params, padded.B))
         fwd = _compiled_dp_scenario_stream_fwd(scenario.init_fn,
                                                scenario.chunk_fn, chunk,
-                                               mesh, dp_backend, donate)
+                                               mesh, donate)
         bwd = _compiled_dp_scenario_stream_bwd(scenario.init_fn,
                                                scenario.chunk_fn, chunk,
-                                               mesh, dp_backend)
+                                               mesh)
         gen0 = _vmap_init(scenario.init_fn, sparams, mesh)
         carry0 = (gen0, J0)
 
@@ -2531,8 +2469,8 @@ def _dp_ckpt_streamed(scenario, padded, mesh, n_chunks, T_pad,
         make_slab = None
     else:
         has_svc = padded.svc is not None
-        fwd = _compiled_dp_stream_fwd(has_svc, mesh, dp_backend, donate)
-        bwd = _compiled_dp_stream_bwd(has_svc, mesh, dp_backend)
+        fwd = _compiled_dp_stream_fwd(has_svc, mesh, donate)
+        bwd = _compiled_dp_stream_bwd(has_svc, mesh)
         carry0 = J0
 
         def call(carry, t0, slabs):
@@ -2585,9 +2523,7 @@ def offline_dp_memory_stats(fleet: FleetBatch, *,
                             checkpointed: bool = False,
                             collect_schedule: bool = True,
                             n_seeds: Optional[int] = None,
-                            antithetic: bool = False,
-                            dp_backend: str = "xla",
-                            prng_backend: str = "xla") -> dict:
+                            antithetic: bool = False) -> dict:
     """XLA-reported memory of the compiled device-scan DP core for this
     config, WITHOUT running it: ``{"argument_bytes", "output_bytes",
     "temp_bytes"}``.  The probed program is built by the same
@@ -2602,15 +2538,12 @@ def offline_dp_memory_stats(fleet: FleetBatch, *,
         # same contract as offline_opt_fleet — never report a program the
         # solver would refuse to run
         raise ValueError("collect_schedule=False requires checkpointed=True")
-    _check_backends(dp_backend, prng_backend, scenario)
     fleet, scenario, _ = _replicate_mc(fleet, scenario, n_seeds, antithetic)
     if scenario is not None:
         _check_scenario(scenario, fleet)
-        scenario = with_prng_backend(scenario, prng_backend)
     mesh, padded, n_chunks, T_pad = _prepare_fleet(fleet, mesh, chunk_size)
     core, args = _dp_scan_core_args(scenario, padded, mesh, n_chunks, T_pad,
-                                    checkpointed, collect_schedule,
-                                    dp_backend)
+                                    checkpointed, collect_schedule)
     stats = core.lower(*args).compile().memory_analysis()
     return {"argument_bytes": int(stats.argument_size_in_bytes),
             "output_bytes": int(stats.output_size_in_bytes),
@@ -2626,8 +2559,6 @@ def offline_opt_fleet(fleet: FleetBatch, *,
                       checkpointed: bool = False,
                       stream: bool = False,
                       collect_schedule: bool = True,
-                      dp_backend: str = "xla",
-                      prng_backend: str = "xla",
                       async_ingest: bool = False,
                       gather: bool = False) -> FleetOfflineResult:
     """Fleet alpha-OPT: the exact DP, sharded over devices and chunked over
@@ -2653,13 +2584,6 @@ def offline_opt_fleet(fleet: FleetBatch, *,
     (``r_hist`` / ``sim`` are None) — the cheapest way to price OPT at
     horizons where even the [B, T] schedule is unwelcome.
 
-    ``dp_backend`` selects the min-plus relaxation engine inside every
-    driver above ("xla" default / "pallas" — see ``dp_fwd_chunk``);
-    ``prng_backend`` the scenario's counter-keyed uniform engine (as in
-    ``run_fleet``).  Backends are a pure performance knob: costs,
-    schedules and sim results are bit-identical across every combination
-    (tests/test_backend_dispatch.py).
-
     ``async_ingest=True`` (streamed, obs-backed fleets) prefetches the
     host->device obs slabs of both DP passes on a background thread —
     double buffering, bit-identical to the synchronous feed (see
@@ -2679,21 +2603,18 @@ def offline_opt_fleet(fleet: FleetBatch, *,
                          "host-driven passes feed slabs)")
     if not collect_schedule and not checkpointed:
         raise ValueError("collect_schedule=False requires checkpointed=True")
-    _check_backends(dp_backend, prng_backend, scenario)
     fleet, scenario, S = _replicate_mc(fleet, scenario, n_seeds, antithetic)
     B, T_max = fleet.B, fleet.T_max
     mesh, padded, n_chunks, T_pad = _prepare_fleet(fleet, mesh, chunk_size)
     if scenario is not None:
         _check_scenario(scenario, fleet)
-        scenario = with_prng_backend(scenario, prng_backend)
     if stream:
         cost, r_hist = _dp_ckpt_streamed(scenario, padded, mesh, n_chunks,
-                                         T_pad, collect_schedule, dp_backend,
+                                         T_pad, collect_schedule,
                                          async_ingest)
     else:
         core, args = _dp_scan_core_args(scenario, padded, mesh, n_chunks,
-                                        T_pad, checkpointed, collect_schedule,
-                                        dp_backend)
+                                        T_pad, checkpointed, collect_schedule)
         out = _dispatch(mesh, core, *args)
         cost, r_hist = out if collect_schedule else (out, None)
     with _span("fleet.readback"):
@@ -2790,7 +2711,6 @@ def evaluate_schedule_fleet(fleet: FleetBatch, r_hist, *,
                             chunk_size: Optional[int] = None,
                             n_seeds: Optional[int] = None,
                             antithetic: bool = False,
-                            prng_backend: str = "xla",
                             gather: bool = False) -> FleetResult:
     """Fleet ``evaluate_schedule``: ``r_hist`` is [B, T_max]; slots past each
     instance's T contribute nothing (and charge no fetch).  With
@@ -2798,14 +2718,10 @@ def evaluate_schedule_fleet(fleet: FleetBatch, r_hist, *,
     ``n_seeds=S`` prices the schedules on S seed-replicas of the scenario
     (``r_hist`` rows may be [B] — repeated per replica — or the full
     [B*S] replication; ``antithetic=True`` pairs the replicas as in
-    ``run_fleet``).  ``prng_backend`` selects the counter-keyed uniform
-    engine as in ``run_fleet`` (bit-identical performance knob)."""
+    ``run_fleet``)."""
     dt = default_float_dtype()
     B_orig = fleet.B
-    _check_backends("xla", prng_backend, scenario)
     fleet, scenario, S = _replicate_mc(fleet, scenario, n_seeds, antithetic)
-    if scenario is not None:
-        scenario = with_prng_backend(scenario, prng_backend)
     B, T_max = fleet.B, fleet.T_max
     mesh, padded, n_chunks, T_pad = _prepare_fleet(fleet, mesh, chunk_size)
     r = np.asarray(r_hist, np.int32)

@@ -32,27 +32,19 @@ identical frontier, the checkpointed schedule is **bit-identical** to the
 materialized one wherever both fit, which is what extends exact OPT to the
 same T = 10^6-10^7 horizons as ``run_fleet(collect_trace=False)``.
 
-**Kernel/reference split** — ``dp_fwd_chunk`` is also the engine's
-backend-dispatch point: ``backend="xla"`` (the default everywhere) runs
-the ``lax.scan`` written below, which is the *canonical reference*
-semantics of the recursion; ``backend="pallas"`` routes the identical
-per-slot op sequence through the fused ``kernels.hosting.dp_minplus_kc``
-kernel (frontier held in VMEM across the chunk, interpret mode on CPU).
-The two are proven **bit-identical** — exact equality of ``(J', args)``,
-not allclose — in tests/test_kernels.py and tests/test_backend_dispatch.py
-for every driver configuration; any future backend must ship the same
-proof before the fleet layer will thread it (ROADMAP engine invariants).
-
-**Frontier-only passes** (pass 1 of the checkpointed cores, the streamed
-forward steps) discard the argmin table and call ``dp_fwd_frontier``.  On
-TPU, with the default backend, a batch of rows whose frontier width lies
-in ``[DP_ROWS_MIN_K, DP_ROWS_MAX_K]`` (the joint multi-service states)
-runs through ``kernels.hosting.dp_minplus_rows`` instead of the vmapped
+**Kernel/reference split** — the ``lax.scan`` in ``dp_fwd_chunk`` is the
+recursion: every driver runs it, and it alone emits the argmin tables the
+backtrack reads.  One kernel stands beside it, taken only where the code
+observes that it wins.  The passes that discard the argmin table (pass 1
+of the checkpointed cores, the streamed forward steps) call
+``dp_fwd_frontier``; on TPU a batch of rows whose frontier width lies in
+``[DP_ROWS_MIN_K, DP_ROWS_MAX_K]`` (the joint multi-service states) runs
+there through ``kernels.hosting.dp_minplus_rows`` instead of the vmapped
 scan: rows on lanes, each row block's fetch matrices read from HBM once
 per chunk instead of once per slot.  The choice follows the width and the
-platform the program lowers for, and the frontiers are bitwise the scan's
-(tests/test_dp_rows_kernel.py); narrower frontiers keep the scan's
-program unchanged.
+platform the program lowers for (``dp_rows_platforms``), and the
+frontiers are bitwise the scan's (tests/test_dp_rows_kernel.py);
+narrower frontiers keep the scan's program unchanged.
 
 ``OPT`` (no partial hosting, the benchmark of [22]) is the same DP on the
 2-level instance. Exhaustive-search cross-checks live in the tests.
@@ -112,35 +104,20 @@ def dp_fetch_matrix(M32, lv32):
     service, so an N=1 joint matrix is bitwise the rank-one product
     below).  Every DP driver builds its fetch matrix here, inside its
     per-instance vmap, which is what threads matrix-M grids through the
-    materialized, checkpointed, streamed, scenario-fused and Pallas paths
-    with no driver changes."""
+    materialized, checkpointed, streamed, scenario-fused and row-kernel
+    paths with no driver changes."""
     if jnp.ndim(M32) >= 2:
         return M32
     return M32 * jnp.maximum(lv32[None, :] - lv32[:, None], 0.0)
 
 
-#: Valid ``backend=`` values for ``dp_fwd_chunk`` (and the ``dp_backend=``
-#: arguments threaded through ``core.fleet``): "xla" is the canonical
-#: ``lax.scan`` reference, "pallas" the fused ``kernels.hosting`` kernel —
-#: bit-identical by the engine's backend-dispatch invariant (ROADMAP.md).
-DP_BACKENDS = ("xla", "pallas")
-
-
-def dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
-                 backend: str = "xla"):
+def dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len):
     """One chunk of the forward value recursion — THE one copy every fleet
     DP driver shares (materialized backpointers, checkpointed two-pass,
     obs-backed and scenario-fused, scan and streamed), so all of them are
     op-for-op the same recursion.  Invalid slots (``t >= T_len``) keep the
     frontier frozen and write identity argmins; padded K levels are priced
     ``+inf`` via ``kmask`` exactly as in ``offline_opt_batch``.
-
-    ``backend`` selects the relaxation engine *under* the shared cost
-    assembly: "xla" (default) is the ``lax.scan`` below — the canonical
-    reference — and "pallas" routes the identical per-slot op sequence
-    through ``kernels.hosting.dp_minplus_kc``, which keeps the [K]
-    frontier kernel-resident across the whole chunk.  Both emit
-    bit-identical ``(J', args)`` for every input.
 
     Returns ``(J', args [chunk, K])``.
     """
@@ -151,17 +128,6 @@ def dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
         wck = (cck[:, None].astype(jnp.float32) * lv32[None, :]
                + sck.astype(jnp.float32))
         wck = jnp.where(kmask[None, :], wck, jnp.inf)
-
-        if backend == "pallas":
-            # lazy import: the kernels package (and Pallas) loads only when a
-            # non-default backend is actually requested
-            # every driver hands in contiguous counters t0 + arange(chunk), so
-            # the live slots are the first T_len - t0
-            from repro.kernels.hosting import dp_minplus_kc
-            return dp_minplus_kc(J, wck, fetch_mat, T_len - tids[0])
-        if backend != "xla":
-            raise ValueError(f"backend must be one of {DP_BACKENDS}, "
-                             f"got {backend!r}")
 
         def fwd(J_prev, inp):
             t, w_t = inp
@@ -197,17 +163,15 @@ def dp_rows_platforms(K: int) -> tuple:
     return ("tpu",) if DP_ROWS_MIN_K <= K <= DP_ROWS_MAX_K else ()
 
 
-def dp_fwd_frontier(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
-                    backend: str = "xla"):
+def dp_fwd_frontier(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len):
     """``dp_fwd_chunk`` for the passes that discard the argmin table: the
-    frontier ``J'`` alone, bitwise the same.  Under ``jax.vmap`` with the
-    default backend, a width that ``dp_rows_platforms`` lists runs every
-    row of the batch through the row-blocked kernel on those platforms
-    (``_dp_fwd_rows``); every other call is ``dp_fwd_chunk``."""
-    if backend == "xla" and dp_rows_platforms(lv32.shape[-1]):
+    frontier ``J'`` alone, bitwise the same.  Under ``jax.vmap``, a width
+    that ``dp_rows_platforms`` lists runs every row of the batch through
+    the row-blocked kernel on those platforms (``_dp_fwd_rows``); every
+    other call is ``dp_fwd_chunk``."""
+    if dp_rows_platforms(lv32.shape[-1]):
         return _dp_fwd_rows(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len)
-    return dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len,
-                        backend)[0]
+    return dp_fwd_chunk(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len)[0]
 
 
 def _dp_fwd_scan(J, tids, cck, sck, lv32, kmask, fetch_mat, T_len):

@@ -12,15 +12,15 @@ See ``base`` for the contract, ``streams`` for the migrated generator
 families, ``combinators`` for mixtures / regime switching / antithetic
 pairing / trace playback.
 """
-from repro.core.scenarios.base import (ObsSlab, PRNG_BACKENDS, Scenario,
-                                       Stream, as_keys, bcast, materialize,
-                                       materialize_stream, shared_keys,
-                                       slot_keys, slot_uniform, split_keys)
+from repro.core.scenarios.base import (ObsSlab, Scenario, Stream, as_keys,
+                                       bcast, materialize, materialize_stream,
+                                       shared_keys, slot_keys, slot_uniform,
+                                       split_keys)
 from repro.core.scenarios.combinators import (antithetic_pairing, combine,
                                               mixture, mixture_from_weights,
                                               regime_switch, replicate_seeds,
                                               tile_services, trace_scenario,
-                                              with_prng_backend, with_seed)
+                                              with_seed)
 from repro.core.scenarios.streams import (adversarial_evict_bait,
                                           adversarial_fetch_bait, arma_rents,
                                           bernoulli_arrivals, bursty_arrivals,
@@ -31,12 +31,12 @@ from repro.core.scenarios.streams import (adversarial_evict_bait,
                                           trace_rents, uniform_rents)
 
 __all__ = [
-    "ObsSlab", "PRNG_BACKENDS", "Scenario", "Stream", "as_keys", "bcast",
+    "ObsSlab", "Scenario", "Stream", "as_keys", "bcast",
     "materialize", "materialize_stream", "shared_keys", "slot_keys",
     "slot_uniform", "split_keys",
     "antithetic_pairing", "combine", "mixture", "mixture_from_weights",
     "regime_switch", "replicate_seeds", "tile_services", "trace_scenario",
-    "with_prng_backend", "with_seed",
+    "with_seed",
     "adversarial_evict_bait", "adversarial_fetch_bait", "arma_rents",
     "bernoulli_arrivals", "bursty_arrivals", "constant_rents", "ge_arrivals",
     "model2_service", "na_rents", "poisson_arrivals", "spot_bounds",

@@ -47,7 +47,6 @@ compose streams without touching the engine.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 from typing import Any, Callable, NamedTuple, Optional
@@ -143,49 +142,16 @@ def slot_keys(key, tids: jnp.ndarray) -> jnp.ndarray:
     return jax.vmap(lambda t: jax.random.fold_in(key, t))(tids)
 
 
-#: Valid PRNG backends: "xla" is the canonical vmapped ``jax.random``
-#: chain, "pallas" the fused ``kernels.hosting.slot_uniform_tc`` kernel
-#: (bit-identical; see the ROADMAP backend-dispatch invariant).  Selected
-#: per trace via ``prng_dispatch`` — use ``combinators.with_prng_backend``
-#: (or the engine entry points' ``prng_backend=``) rather than calling the
-#: context manager directly.
-PRNG_BACKENDS = ("xla", "pallas")
-
-# trace-time backend stack; slot_uniform consults the top.  A plain list,
-# not a contextvar: dispatch happens while *tracing* a chunk_fn, which the
-# with_prng_backend wrapper brackets synchronously.
-_PRNG_BACKEND = ["xla"]
-
-
-@contextlib.contextmanager
-def prng_dispatch(backend: str):
-    """Route ``slot_uniform`` through ``backend`` for the enclosed trace."""
-    if backend not in PRNG_BACKENDS:
-        raise ValueError(f"prng backend must be one of {PRNG_BACKENDS}, "
-                         f"got {backend!r}")
-    _PRNG_BACKEND.append(backend)
-    try:
-        yield
-    finally:
-        _PRNG_BACKEND.pop()
-
-
 def slot_uniform(key, tids: jnp.ndarray, salt: Optional[int] = None,
                  dtype=jnp.float32) -> jnp.ndarray:
     """[chunk] independent U(0,1) draws, one per global slot index.
 
     THE counter-keyed uniform primitive every hot stream draws through
     (``bernoulli_arrivals``, ``uniform_rents`` / ``na_rents``, the GE chain
-    and its bernoulli emissions) — and therefore the PRNG backend-dispatch
-    point: under ``prng_dispatch("pallas")`` the whole fold/salt/uniform
-    chain runs as one fused ``kernels.hosting`` pass, bit-identical to the
-    vmapped ``jax.random`` chain below (non-float32 ``dtype`` — the x64
-    path — always uses the reference chain).
+    and its bernoulli emissions): slot ``t`` draws from
+    ``fold_in(fold_in(key, t), salt)`` (the salt fold only when given), so
+    the draws at any split of the counters are the draws of the whole.
     """
-    if (_PRNG_BACKEND[-1] == "pallas"
-            and jnp.dtype(dtype) == jnp.dtype(jnp.float32)):
-        from repro.kernels.hosting import slot_uniform_tc
-        return slot_uniform_tc(jnp.asarray(key), tids, salt)
     ks = slot_keys(key, tids)
     if salt is not None:
         ks = jax.vmap(lambda k: jax.random.fold_in(k, salt))(ks)

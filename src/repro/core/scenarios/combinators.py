@@ -11,10 +11,6 @@ observation arrays.
 * ``trace_scenario``      — deterministic playback of recorded [B, T] obs.
 * ``with_seed``           — fold one Monte-Carlo seed into every stream key
                             (before the per-slot counter fold).
-* ``with_prng_backend``   — route a scenario's (or stream's) counter-keyed
-                            uniforms through a kernel backend
-                            (``base.PRNG_BACKENDS``); bit-identical by the
-                            backend-dispatch invariant.
 * ``replicate_seeds``     — the MC axis: S seed-replicas of a B-instance
                             scenario as one [B*S] scenario
                             (``antithetic=True`` pairs replicas (2m, 2m+1)
@@ -43,47 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.scenarios.base import (ObsSlab, PRNG_BACKENDS, Scenario,
-                                       Stream, prng_dispatch)
+from repro.core.scenarios.base import ObsSlab, Scenario, Stream
 from repro.core.scenarios import streams as _streams
-
-
-@functools.lru_cache(maxsize=64)
-def _backend_fns(init_fn, chunk_fn, backend: str):
-    """Backend-bracketed (init_fn, chunk_fn), memoized on the wrapped
-    *functions* + backend so repeated with_prng_backend() constructions
-    yield identical function objects (the identity-keyed compile caches —
-    ``base._compiled_gen``, the fleet engine cores — then key correctly on
-    the backend choice, like ``_combine_fns``)."""
-
-    def init2(params):
-        with prng_dispatch(backend):
-            return init_fn(params)
-
-    def chunk2(params, state, tids, *extra):
-        with prng_dispatch(backend):
-            return chunk_fn(params, state, tids, *extra)
-
-    return init2, chunk2
-
-
-def with_prng_backend(scenario, backend: str):
-    """Route every ``slot_uniform`` draw of a Scenario (or a single Stream)
-    through ``backend`` (see ``base.PRNG_BACKENDS``).  "xla" — the
-    canonical reference — returns the input unchanged; any other backend
-    wraps ``init_fn``/``chunk_fn`` so the dispatch is baked in at trace
-    time.  Observations are **bit-identical** across backends (the
-    backend-dispatch invariant); draws the kernel does not cover (poisson,
-    normal, float64 uniforms) silently stay on the reference path."""
-    if backend not in PRNG_BACKENDS:
-        raise ValueError(f"prng backend must be one of {PRNG_BACKENDS}, "
-                         f"got {backend!r}")
-    if backend == "xla":
-        return scenario
-    init2, chunk2 = _backend_fns(scenario.init_fn, scenario.chunk_fn,
-                                 backend)
-    return scenario._replace(init_fn=init2, chunk_fn=chunk2,
-                             name=f"{scenario.name}@{backend}")
 
 
 @functools.lru_cache(maxsize=256)

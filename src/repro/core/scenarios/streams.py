@@ -175,7 +175,6 @@ def _ge_emit(key, tids, rates, emission: str, salt: int):
         return _poisson_draws(ks, rates, emission == "poisson_knuth")
     if emission == "bernoulli":
         # the fold/salt/uniform chain IS slot_uniform's — draw through it
-        # so GE bernoulli emissions ride the PRNG backend dispatch too
         u = slot_uniform(key, tids, salt=salt)
         return (u < rates).astype(jnp.int32)
     raise ValueError(emission)

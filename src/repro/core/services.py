@@ -362,7 +362,7 @@ def offline_opt_services(sfleet: ServiceFleet, *, scenario: Scenario,
                          **kwargs) -> ServiceOfflineResult:
     """The joint capacity-respecting OPT: the UNCHANGED fleet DP
     (``offline_opt_fleet`` — materialized or checkpointed, chunked or
-    streamed, any ``dp_backend``) over the joint-state grid, driven by the
+    streamed) over the joint-state grid, driven by the
     joint scenario.  Every keyword passes through.  Feasibility is free:
     infeasible level combinations are simply not states."""
     res = offline_opt_fleet(sfleet.joint_fleet(),

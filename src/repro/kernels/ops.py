@@ -1,10 +1,11 @@
-"""Jitted public wrappers around the Pallas kernels: layout handling,
-padding to block multiples, and dtype plumbing.  ``interpret=None`` (the
-default) resolves through ``kernels.utils.default_interpret``: the Pallas
-interpreter on CPU, the compiled Mosaic kernel on TPU.
+"""Jitted public wrappers around the model Pallas kernels (flash attention,
+the SSD scan): layout handling, padding to block multiples, and dtype
+plumbing.  ``interpret=None`` (the default) resolves through
+``kernels.utils.default_interpret``: the Pallas interpreter on CPU, the
+compiled Mosaic kernel on TPU.
 
 Padding/block-fitting arithmetic lives in ``kernels.utils`` (one shared
-copy, also used by the hosting kernels' own wrappers).
+copy, also used by the hosting kernel ``hosting.dp_minplus_rows``).
 """
 from __future__ import annotations
 
@@ -12,10 +13,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels.flash_attention import flash_attention_bhsd
-from repro.kernels.hosting import dp_minplus_kc, slot_uniform_tc
 from repro.kernels.ssd_scan import ssd_scan_bhcqd
 from repro.kernels.utils import default_interpret, fit_block, pad_to as _pad_to
 
@@ -66,34 +65,3 @@ def ssd_scan(x, dt, A, B, C, h0=None, chunk: int = 128, interpret=None):
                            h0.astype(jnp.float32), interpret=interpret)
     y = jnp.moveaxis(y, 1, 3).reshape(b, nc * q, nh, dh)[:, :s]
     return y, hT
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def dp_minplus(J, wck, fetch_mat, n_live, interpret=None):
-    """Fused DP min-plus forward chunk (``hosting.dp_minplus_kc``).
-
-    Per-instance: J [K], wck [chunk, K], fetch_mat [K, K], n_live int32
-    scalar (slots ``t < n_live`` are live); batched: a leading [B] axis on
-    every arg.  Returns ``(J', args)`` — bit-identical to
-    ``offline_opt.dp_fwd_chunk``'s scan.
-    """
-    if J.ndim == 2:
-        return jax.vmap(lambda j, w, f, n: dp_minplus_kc(
-            j, w, f, n, interpret=interpret))(J, wck, fetch_mat, n_live)
-    return dp_minplus_kc(J, wck, fetch_mat, n_live, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("salt", "interpret"))
-def counter_uniforms(keys, tids, salt=None, interpret=None):
-    """Fused counter-keyed uniforms (``hosting.slot_uniform_tc``).
-
-    ``keys`` raw uint32 [2] (one instance) or [B, 2]; ``tids`` [chunk]
-    int32 slot counters; ``salt`` optional static int.  Returns [chunk]
-    or [B, chunk] float32, bit-identical to
-    ``scenarios.base.slot_uniform``.
-    """
-    keys = jnp.asarray(keys)
-    if keys.ndim == 2:
-        return jax.vmap(lambda k: slot_uniform_tc(
-            k, tids, salt, interpret=interpret))(keys)
-    return slot_uniform_tc(keys, tids, salt, interpret=interpret)

@@ -3,9 +3,9 @@ multiples, block-size fitting, and the interpret-default resolution.
 
 The model-kernel wrappers in ``ops.py`` pad their inputs up to the
 kernel's block multiple, run the kernel, and slice the pad back off — this
-module is the ONE copy of that arithmetic.  (The per-instance hosting
-kernels take whole blocks and need no padding; the row-blocked DP forward
-pads its rows and slots here.)
+module is the ONE copy of that arithmetic, which the row-blocked DP
+forward (``hosting.dp_minplus_rows``) also uses to pad its rows and
+slots.
 """
 from __future__ import annotations
 

@@ -6,8 +6,10 @@ of the same float32 sums in another order, and a minimum rounds nothing.
 The kernel runs in interpret mode here.  The cases cover a real joint
 fetch matrix (J = 50), an odd width, a mixed-J grid with padded ``+inf``
 states, a row count that is not a multiple of 128, horizons that end
-inside or before the chunk, and integer costs that force ties.  The last
-test runs the whole joint OPT with the row-blocked path switched on
+inside or before the chunk, and integer costs that force ties.  The
+kernel is also held, one row per case, to ``test_kernels.py``'s float32
+numpy replay of the recursion: the same cases, ties and chained chunks
+on which the scan meets it.  The last test runs the whole joint OPT with the row-blocked path switched on
 through ``offline_opt.dp_rows_platforms``, and its costs must be bitwise
 those of the scan.
 """
@@ -23,6 +25,8 @@ from repro.core import scenarios as S
 from repro.core import services as SV
 from repro.core.costs import HostingCosts, ServiceSet, joint_hosting_grid
 from repro.kernels import hosting as H
+from test_kernels import (DP_CASES, _dp_case, dp_case_seed, dp_ties_case,
+                          np_dp_forward, np_dp_holding)
 
 O = importlib.import_module("repro.core.policies.offline_opt")
 
@@ -71,6 +75,49 @@ def _check(J, w, fetch, n_live):
     out = _rows(J, w, fetch, n_live)
     assert np.isfinite(ref).any()
     np.testing.assert_array_equal(out, ref)
+
+
+def _oracle_rows(J0, tids, cck, sck, lv32, kmask, fetch, T_len):
+    """One DP case of ``test_kernels.py`` as one row of ``_rows``: the
+    holding costs the oracle prices, and the slots live from ``tids[0]``
+    on."""
+    return (np.asarray(J0)[None], np_dp_holding(cck, sck, lv32, kmask)[None],
+            np.asarray(fetch)[None],
+            jnp.asarray([int(T_len) - int(tids[0])], jnp.int32))
+
+
+@pytest.mark.parametrize("chunk,K,k_used,T_len", DP_CASES)
+def test_rows_kernel_matches_numpy_oracle(chunk, K, k_used, T_len):
+    """Masked levels, frozen tails and a fully frozen chunk, as the scan
+    meets them in ``test_kernels.py``."""
+    case = _dp_case(dp_case_seed(chunk, K), chunk, K, k_used, T_len)
+    out = _rows(*_oracle_rows(*case))
+    np.testing.assert_array_equal(out[0], np_dp_forward(*case)[0])
+
+
+def test_rows_kernel_ties_match_numpy_oracle():
+    """Every predecessor tied (a zero fetch matrix)."""
+    case = dp_ties_case()
+    out = _rows(*_oracle_rows(*case))
+    np.testing.assert_array_equal(out[0], np_dp_forward(*case)[0])
+
+
+def test_rows_kernel_chained_chunks_match():
+    """Two 16-slot calls, the frontier carried from one to the next, equal
+    one 32-slot call and the oracle, with the horizon ending inside the
+    second chunk."""
+    J0, tids, cck, sck, lv32, kmask, fetch, _ = _dp_case(3, 32, 5, 4, 32)
+    T_len = jnp.int32(27)
+    whole = _rows(*_oracle_rows(J0, tids, cck, sck, lv32, kmask, fetch,
+                                T_len))
+    J = np.asarray(J0)
+    for t0 in (0, 16):
+        cut = slice(t0, t0 + 16)
+        J = _rows(*_oracle_rows(J, tids[cut], cck[cut], sck[cut], lv32,
+                                kmask, fetch, T_len))[0]
+    np.testing.assert_array_equal(J, whole[0])
+    np.testing.assert_array_equal(
+        J, np_dp_forward(J0, tids, cck, sck, lv32, kmask, fetch, T_len)[0])
 
 
 def test_real_joint_fetch_matrices_J50():

@@ -1,5 +1,9 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
-(the kernel body executes in Python on CPU) + hypothesis property tests."""
+(the kernel body executes in Python on CPU) + hypothesis property tests;
+then the hosting engine's DP recursion and its counter-keyed uniform
+against independent references."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -132,15 +136,16 @@ def test_model_attention_pallas_path_matches_xla():
 
 
 # ----------------------------------------------------------------------
-# Hosting kernels (kernels.hosting): DP min-plus + counter-keyed threefry.
-# Unlike the float kernels above these are bit-EXACT vs the engine's XLA
-# references — array_equal, never allclose (the backend-dispatch
-# invariant: a backend is a performance knob, not a numerics choice).
+# The hosting DP recursion: ``offline_opt.dp_fwd_chunk``, the lax.scan
+# every fleet driver runs, against an independent float32 numpy replay.
+# Bit-EXACT — array_equal, never allclose; tests/test_dp_rows_kernel.py
+# holds the row-blocked kernel ``kernels.hosting.dp_minplus_rows`` to the
+# same oracle.
 # ----------------------------------------------------------------------
 
 from repro.core.policies.offline_opt import (dp_fetch_matrix, dp_frontier0,
                                              dp_fwd_chunk)
-from repro.kernels.hosting import slot_uniform_tc, threefry_fold
+from repro.core.scenarios.base import slot_uniform
 
 
 def _dp_case(seed, chunk, K, k_used, T_len):
@@ -156,6 +161,32 @@ def _dp_case(seed, chunk, K, k_used, T_len):
             jnp.asarray(T_len, jnp.int32))
 
 
+def np_dp_holding(cck, sck, lv32, kmask):
+    """The float32 per-slot holding costs ``[chunk, K]``: rent times level
+    plus service, ``+inf`` on masked levels."""
+    w = (np.asarray(cck)[:, None] * np.asarray(lv32)[None, :]
+         + np.asarray(sck)).astype(np.float32)
+    return np.where(np.asarray(kmask)[None, :], w, np.float32(np.inf))
+
+
+def np_dp_forward(J0, tids, cck, sck, lv32, kmask, fetch, T_len):
+    """Independent float32 numpy replay of one chunk of the recursion:
+    first-occurrence argmin (np.argmin's documented tie rule), ``+inf`` on
+    masked levels, and at slots ``t >= T_len`` a frozen frontier with
+    identity argmins.  Returns ``(J', args [chunk, K])``."""
+    w = np_dp_holding(cck, sck, lv32, kmask)
+    fm = np.asarray(fetch)
+    J, args = np.asarray(J0), []
+    for i, t in enumerate(np.asarray(tids)):
+        if t >= int(T_len):
+            args.append(np.arange(J.shape[0]))
+            continue
+        trans = (J[:, None] + fm).astype(np.float32)
+        args.append(trans.argmin(axis=0))
+        J = (trans.min(axis=0) + w[i]).astype(np.float32)
+    return J, np.stack(args)
+
+
 DP_CASES = [
     # (chunk, K, k_used, T_len): aligned/odd chunks, +inf kmask pads,
     # frozen tails (T_len < chunk) and fully-frozen (T_len = 0)
@@ -168,54 +199,57 @@ DP_CASES = [
 ]
 
 
+def dp_case_seed(chunk, K):
+    return chunk * 7 + K
+
+
 @pytest.mark.parametrize("chunk,K,k_used,T_len", DP_CASES)
 def test_dp_minplus_matches_xla_reference(chunk, K, k_used, T_len):
-    case = _dp_case(chunk * 7 + K, chunk, K, k_used, T_len)
-    Jx, ax = dp_fwd_chunk(*case, "xla")
-    Jp, ap = dp_fwd_chunk(*case, "pallas")
-    assert np.array_equal(np.asarray(Jx), np.asarray(Jp))
-    assert np.array_equal(np.asarray(ax), np.asarray(ap))
+    """The scan against the numpy oracle on every case: masked levels
+    and frozen tails included."""
+    case = _dp_case(dp_case_seed(chunk, K), chunk, K, k_used, T_len)
+    J, args = dp_fwd_chunk(*case)
+    J_ref, args_ref = np_dp_forward(*case)
+    assert np.array_equal(np.asarray(J), J_ref)
+    assert np.array_equal(np.asarray(args), args_ref)
 
 
 def test_dp_minplus_chained_chunks_match():
     """The frontier carried across chunk boundaries stays exact: two
-    16-slot pallas chunks == one 32-slot xla chunk, J and args."""
+    16-slot chunks == one 32-slot chunk == the oracle, J and args, with
+    the horizon ending inside the second chunk."""
     (J0, _, cck, sck, lv32, kmask, fetch, _) = _dp_case(3, 32, 5, 4, 32)
-    Jx, ax = dp_fwd_chunk(J0, jnp.arange(32, dtype=jnp.int32), cck, sck,
-                          lv32, kmask, fetch, jnp.int32(27), "xla")
+    tids = jnp.arange(32, dtype=jnp.int32)
+    Jx, ax = dp_fwd_chunk(J0, tids, cck, sck, lv32, kmask, fetch,
+                          jnp.int32(27))
     J, parts = J0, []
     for t0 in (0, 16):
-        tids = t0 + jnp.arange(16, dtype=jnp.int32)
-        J, a = dp_fwd_chunk(J, tids, cck[t0:t0 + 16], sck[t0:t0 + 16],
-                            lv32, kmask, fetch, jnp.int32(27), "pallas")
+        J, a = dp_fwd_chunk(J, tids[t0:t0 + 16], cck[t0:t0 + 16],
+                            sck[t0:t0 + 16], lv32, kmask, fetch,
+                            jnp.int32(27))
         parts.append(np.asarray(a))
+    J_ref, args_ref = np_dp_forward(J0, tids, cck, sck, lv32, kmask, fetch,
+                                    27)
     assert np.array_equal(np.asarray(Jx), np.asarray(J))
     assert np.array_equal(np.asarray(ax), np.concatenate(parts))
+    assert np.array_equal(np.asarray(J), J_ref)
+    assert np.array_equal(np.asarray(ax), args_ref)
 
 
 def test_dp_minplus_numpy_oracle():
-    """Independent float32 numpy replay of the recursion — same values AND
-    first-occurrence argmin (np.argmin's documented tie rule)."""
+    """A 24-slot chunk with every level live: same values AND
+    first-occurrence argmin as the numpy replay."""
     chunk, K = 24, 4
     case = _dp_case(11, chunk, K, K, chunk)
-    J0, tids, cck, sck, lv32, kmask, fetch, T_len = case
-    w = np.asarray(cck)[:, None] * np.asarray(lv32)[None, :] + np.asarray(sck)
-    J, args_ref = np.asarray(J0), []
-    fm = np.asarray(fetch)
-    for t in range(chunk):
-        trans = (J[:, None] + fm).astype(np.float32)
-        args_ref.append(trans.argmin(axis=0))
-        J = (trans.min(axis=0) + w[t]).astype(np.float32)
-    for backend in ("xla", "pallas"):
-        Jb, ab = dp_fwd_chunk(*case, backend)
-        assert np.array_equal(np.asarray(Jb), J), backend
-        assert np.array_equal(np.asarray(ab), np.stack(args_ref)), backend
+    J_ref, args_ref = np_dp_forward(*case)
+    J, args = dp_fwd_chunk(*case)
+    assert np.array_equal(np.asarray(J), J_ref)
+    assert np.array_equal(np.asarray(args), args_ref)
 
 
-def test_dp_argmin_ties_resolve_to_lowest_index():
+def dp_ties_case():
     """Crafted all-equal-cost fixture: with a zero fetch matrix every
-    predecessor ties, so the argmin table must be the lowest index holding
-    the running min — for both backends, identically."""
+    predecessor ties."""
     K, chunk = 4, 6
     lv32 = jnp.linspace(0.0, 1.0, K, dtype=jnp.float32)
     fetch = dp_fetch_matrix(jnp.float32(0.0), lv32)     # all-zero fetch
@@ -224,76 +258,70 @@ def test_dp_argmin_ties_resolve_to_lowest_index():
     sck = jnp.zeros((chunk, K), jnp.float32)            # w == 0
     tids = jnp.arange(chunk, dtype=jnp.int32)
     kmask = jnp.ones((K,), bool)
-    for backend in ("xla", "pallas"):
-        J, args = dp_fwd_chunk(J0, tids, cck, sck, lv32, kmask, fetch,
-                               jnp.int32(chunk), backend)
-        # slot 0: levels {1,2,3} tie at 0 -> index 1; after that J == 0
-        # everywhere so all K levels tie -> index 0
-        want = np.ones((chunk, K), np.int64)
-        want[1:] = 0
-        assert np.array_equal(np.asarray(args), want), backend
-        assert np.array_equal(np.asarray(J), np.zeros(K, np.float32)), backend
+    return J0, tids, cck, sck, lv32, kmask, fetch, jnp.int32(chunk)
+
+
+def test_dp_argmin_ties_resolve_to_lowest_index():
+    """With every predecessor tied, the argmin table must be the lowest
+    index holding the running min."""
+    case = dp_ties_case()
+    chunk, K = case[3].shape
+    J, args = dp_fwd_chunk(*case)
+    # slot 0: levels {1,2,3} tie at 0 -> index 1; after that J == 0
+    # everywhere so all K levels tie -> index 0
+    want = np.ones((chunk, K), np.int64)
+    want[1:] = 0
+    assert np.array_equal(np.asarray(args), want)
+    assert np.array_equal(np.asarray(J), np.zeros(K, np.float32))
 
 
 @settings(max_examples=12, deadline=None)
 @given(st.integers(0, 2 ** 31 - 1), st.integers(1, 40), st.integers(2, 6))
 def test_dp_argmin_tie_property(seed, chunk, K):
     """Hypothesis: costs drawn from a coarse half-integer grid force
-    frequent exact ties; both backends must match the numpy
-    first-occurrence (lowest-predecessor-index) oracle bit-for-bit."""
+    frequent exact ties; the scan must match the numpy first-occurrence
+    (lowest-predecessor-index) oracle bit-for-bit."""
     rng = np.random.default_rng(seed)
     lv32 = jnp.linspace(0.0, 1.0, K, dtype=jnp.float32)
     fetch = dp_fetch_matrix(jnp.float32(rng.integers(0, 3) * 2.0), lv32)
     w = (rng.integers(0, 4, (chunk, K)) / 2.0).astype(np.float32)
     J0 = jnp.asarray(rng.integers(0, 3, K) / 2.0, jnp.float32)
-    tids = jnp.arange(chunk, dtype=jnp.int32)
-    kmask = jnp.ones((K,), bool)
-    J, args_ref = np.asarray(J0), []
-    fm = np.asarray(fetch)
-    for t in range(chunk):
-        trans = (J[:, None] + fm).astype(np.float32)
-        args_ref.append(trans.argmin(axis=0))
-        J = (trans.min(axis=0) + w[t]).astype(np.float32)
-    for backend in ("xla", "pallas"):
-        Jb, ab = dp_fwd_chunk(J0, tids, jnp.zeros(chunk, jnp.float32),
-                              jnp.asarray(w), lv32, kmask, fetch,
-                              jnp.int32(chunk), backend)
-        assert np.array_equal(np.asarray(ab), np.stack(args_ref)), backend
-        assert np.array_equal(np.asarray(Jb), J), backend
-
-
-def test_dp_minplus_batched_wrapper():
-    """ops.dp_minplus vmaps the kernel over [B]; rows match per-instance
-    XLA references exactly."""
-    cases = [_dp_case(s, 20, 5, k, t)
-             for s, (k, t) in enumerate([(5, 20), (3, 7), (2, 0)])]
-    J = jnp.stack([c[0] for c in cases])
-    w = []
-    n_live = []
-    for c in cases:
-        _, tids, cck, sck, lv32, kmask, _, T_len = c
-        wck = cck[:, None] * lv32[None, :] + sck
-        w.append(jnp.where(kmask[None, :], wck, jnp.inf))
-        n_live.append(T_len - tids[0])
-    Jb, ab = ops.dp_minplus(J, jnp.stack(w),
-                            jnp.stack([c[6] for c in cases]),
-                            jnp.stack(n_live))
-    for i, c in enumerate(cases):
-        Jx, ax = dp_fwd_chunk(*c, "xla")
-        assert np.array_equal(np.asarray(Jb[i]), np.asarray(Jx)), i
-        assert np.array_equal(np.asarray(ab[i]), np.asarray(ax)), i
+    case = (J0, jnp.arange(chunk, dtype=jnp.int32),
+            jnp.zeros(chunk, jnp.float32), jnp.asarray(w), lv32,
+            jnp.ones((K,), bool), fetch, jnp.int32(chunk))
+    J_ref, args_ref = np_dp_forward(*case)
+    J, args = dp_fwd_chunk(*case)
+    assert np.array_equal(np.asarray(args), args_ref)
+    assert np.array_equal(np.asarray(J), J_ref)
 
 
 # ---------------------------------------------------------------------
-# Counter-PRNG kernel vs jax.random primitives (bit-equality).
+# The counter-keyed uniform (scenarios.base.slot_uniform) against the
+# contract every stream relies on: the draws at counters t0 + arange(chunk)
+# are the draws at any split of those counters, and each is the draw made
+# slot by slot from fold_in(fold_in(key, t), salt).
 # ---------------------------------------------------------------------
 
-def _ref_uniform(key, tids, salt):
-    """The canonical vmapped chain from scenarios.base.slot_uniform."""
-    ks = jax.vmap(lambda t: jax.random.fold_in(key, t))(tids)
-    if salt is not None:
-        ks = jax.vmap(lambda k: jax.random.fold_in(k, salt))(ks)
-    return jax.vmap(lambda k: jax.random.uniform(k, (), jnp.float32))(ks)
+@functools.lru_cache(maxsize=None)
+def _one_slot_uniform(salt):
+    """One slot's draw, from its own folded key alone."""
+    def one(key, t):
+        k = jax.random.fold_in(key, t)
+        if salt is not None:
+            k = jax.random.fold_in(k, salt)
+        return jax.random.uniform(k, (), jnp.float32)
+    return jax.jit(one)
+
+
+def _check_slot_uniform(key, tids, salt, split):
+    got = np.asarray(slot_uniform(key, tids, salt))
+    one = _one_slot_uniform(salt)
+    want = np.stack([np.asarray(one(key, t)) for t in tids])
+    assert np.array_equal(got, want)
+    parts = [slot_uniform(key, tids[:split], salt),
+             slot_uniform(key, tids[split:], salt)]
+    assert np.array_equal(got, np.concatenate([np.asarray(p)
+                                               for p in parts]))
 
 
 @pytest.mark.parametrize("salt", [None, 0, 1, 2, 0x7FFFFFFF])
@@ -301,40 +329,19 @@ def _ref_uniform(key, tids, salt):
 def test_slot_uniform_bits_match_jax_random(salt, chunk):
     key = jax.random.PRNGKey(42)
     tids = jnp.arange(chunk, dtype=jnp.int32) + 5
-    got = slot_uniform_tc(jnp.asarray(key, jnp.uint32), tids, salt)
-    assert np.array_equal(np.asarray(got), np.asarray(_ref_uniform(key, tids, salt)))
-
-
-def test_threefry_fold_matches_fold_in():
-    """The in-kernel threefry2x32 reimplementation == jax.random.fold_in
-    at the key level, not just after the uniform mapping."""
-    key = jax.random.PRNGKey(3)
-    d = jnp.arange(64, dtype=jnp.uint32) * 977 + 13
-    x0, x1 = threefry_fold(jnp.uint32(key[0]), jnp.uint32(key[1]), d)
-    want = jax.vmap(lambda t: jax.random.fold_in(key, t))(d)
-    assert np.array_equal(np.asarray(jnp.stack([x0, x1], -1)),
-                          np.asarray(want))
+    _check_slot_uniform(key, tids, salt, chunk // 3)
 
 
 def test_bernoulli_bits_match_jax_random():
-    """(kernel uniform < p) == jax.random.bernoulli on the folded key —
+    """(slot uniform < p) == jax.random.bernoulli on the folded key —
     the exact op chain bernoulli_arrivals / the GE emitter use."""
     key = jax.random.PRNGKey(7)
     tids = jnp.arange(37, dtype=jnp.int32)
+    u = slot_uniform(key, tids)
     for p in (0.0, 0.25, 0.4, 1.0):
-        u = slot_uniform_tc(jnp.asarray(key, jnp.uint32), tids, None)
         want = jax.vmap(lambda t: jax.random.bernoulli(
             jax.random.fold_in(key, t), p))(tids)
         assert np.array_equal(np.asarray(u < p), np.asarray(want)), p
-
-
-def test_counter_uniforms_batched_wrapper():
-    keys = jax.random.split(jax.random.PRNGKey(5), 4)
-    tids = jnp.arange(23, dtype=jnp.int32) + 100
-    got = ops.counter_uniforms(jnp.asarray(keys, jnp.uint32), tids, salt=2)
-    for i in range(4):
-        want = _ref_uniform(keys[i], tids, 2)
-        assert np.array_equal(np.asarray(got[i]), np.asarray(want)), i
 
 
 @settings(max_examples=12, deadline=None)
@@ -343,31 +350,7 @@ def test_counter_uniforms_batched_wrapper():
        st.integers(0, 10 ** 6))
 def test_slot_uniform_property(seed, chunk, salt, t0):
     """Random keys x random salts x non-aligned chunk sizes x arbitrary
-    counter offsets: always the exact jax.random bits."""
+    counter offsets x a split point: always the slot-by-slot bits."""
     key = jax.random.PRNGKey(seed)
     tids = t0 + jnp.arange(chunk, dtype=jnp.int32)
-    got = slot_uniform_tc(jnp.asarray(key, jnp.uint32), tids, salt)
-    assert np.array_equal(np.asarray(got),
-                          np.asarray(_ref_uniform(key, tids, salt)))
-
-
-@pytest.mark.skipif(jax.default_backend() == "cpu",
-                    reason="compiled (non-interpret) Pallas needs an "
-                           "accelerator backend; CPU covers interpret mode")
-def test_hosting_kernels_compiled_mode():
-    """On an accelerator the compiled kernels must match too (interpret
-    mode is what the CPU suite proves)."""
-    case = _dp_case(1, 32, 5, 4, 25)
-    Jx, ax = dp_fwd_chunk(*case, "xla")
-    from repro.kernels.hosting import dp_minplus_kc
-    J0, tids, cck, sck, lv32, kmask, fetch, T_len = case
-    wck = jnp.where(kmask[None, :],
-                    cck[:, None] * lv32[None, :] + sck, jnp.inf)
-    Jp, ap = dp_minplus_kc(J0, wck, fetch, T_len - tids[0], interpret=False)
-    assert np.array_equal(np.asarray(Jx), np.asarray(Jp))
-    assert np.array_equal(np.asarray(ax), np.asarray(ap))
-    key = jax.random.PRNGKey(9)
-    u = slot_uniform_tc(jnp.asarray(key, jnp.uint32),
-                        jnp.arange(37, dtype=jnp.int32), 1, interpret=False)
-    want = _ref_uniform(key, jnp.arange(37, dtype=jnp.int32), 1)
-    assert np.array_equal(np.asarray(u), np.asarray(want))
+    _check_slot_uniform(key, tids, salt, (seed % chunk) if chunk else 0)

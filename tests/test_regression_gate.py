@@ -231,20 +231,6 @@ def healthy_rows():
             "slots_instances_per_sec": 1.0,
             "joint_dp_seconds": 1.0,
         },
-        {
-            "name": "dp_minplus_kernel",
-            "identical_bits": True,
-            "xla_dp_slots_instances_per_sec": 1.0,
-            "pallas_dp_slots_instances_per_sec": 1.0,
-            "backend": "pallas-interpret",
-        },
-        {
-            "name": "counter_prng_kernel",
-            "identical_bits": True,
-            "xla_prng_draws_per_sec": 1.0,
-            "pallas_prng_draws_per_sec": 1.0,
-            "backend": "pallas-interpret",
-        },
     ]
 
 

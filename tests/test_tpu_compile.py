@@ -3,14 +3,12 @@
 Nothing runs: each test lowers and compiles for one chip of a ``v5e:2x2``
 topology that the installed TPU compiler describes without the hardware,
 so what Mosaic or XLA:TPU would refuse (block shapes, VMEM, HBM) fails
-here.  The kernels are compiled with ``interpret=False`` in the vmapped
-form the fleet engine runs (the row-blocked DP forward in the rows-minor
-form it takes, at the multi-tenant cell's width); the XLA programs at the
-fleet width the chip smoke run uses (B = 4096, chunk = 4096).  A lowering
-for the chip also shows which frontier widths take the row-blocked
-kernel.  The topology is described inside
-a fixture, never at import time, and every compile runs in the test's own
-process.
+here.  The row-blocked DP forward is compiled with ``interpret=False``
+in the rows-minor form the fleet engine gives it, at every frontier width
+it may take; the XLA programs at a fleet width of B = 4096 and a chunk of
+4096 slots.  A lowering for the chip also shows which frontier widths
+take the row-blocked kernel.  The topology is described inside a fixture,
+never at import time, and every compile runs in the test's own process.
 """
 import functools
 
@@ -25,8 +23,7 @@ from repro.core.policies.alpha_rr import (alpha_rr_init, alpha_rr_params,
 from repro.core.policies.offline_opt import (DP_ROWS_TRACES, dp_fwd_chunk,
                                              dp_fwd_frontier)
 from repro.core.simulator import sim_acc0, sim_chunk_core
-from repro.kernels.hosting import (dp_minplus_kc, dp_minplus_rows,
-                                   slot_uniform_tc)
+from repro.kernels.hosting import dp_minplus_rows
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
 CHUNK = 4096
@@ -65,23 +62,11 @@ def _fits(compiled):
     assert used < HBM_BYTES, used
 
 
-@pytest.mark.parametrize("K", [3, 8])
-def test_dp_minplus_kernel_compiles_vmapped(one_chip, K):
-    B = 64
-    f32 = jnp.float32
-    kernel = functools.partial(dp_minplus_kc, interpret=False)
-    compiled = jax.jit(jax.vmap(kernel)).lower(
-        _sds(one_chip, (B, K), f32), _sds(one_chip, (B, CHUNK, K), f32),
-        _sds(one_chip, (B, K, K), f32),
-        _sds(one_chip, (B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("K", [50, 64])
+@pytest.mark.parametrize("K", [8, 16, 32, 50, 64])
 def test_dp_minplus_rows_compiles_at_the_joint_width(one_chip, K):
     """The row-blocked forward at the multi-tenant cell's size (16384 rows,
-    J = 50 joint states, a chunk of 512 slots) and at the widest frontier
-    the engine gives it."""
+    J = 50 joint states, a chunk of 512 slots), and at that size for every
+    width from ``DP_ROWS_MIN_K`` = 8 to ``DP_ROWS_MAX_K`` = 64."""
     R, chunk = 16384, 512
     f32 = jnp.float32
     kernel = functools.partial(dp_minplus_rows, interpret=False)
@@ -93,11 +78,12 @@ def test_dp_minplus_rows_compiles_at_the_joint_width(one_chip, K):
     _fits(compiled)
 
 
-@pytest.mark.parametrize("K", [3, 50])
+@pytest.mark.parametrize("K", [3, 7, 8, 50, 64, 65])
 def test_frontier_forward_takes_the_rows_kernel_by_width(one_chip, K):
     """Lowered for the chip, the batched frontier-only forward runs
-    ``dp_minplus_rows`` at J = 50 and counts the trace; at K = 3 it keeps
-    the scan and counts nothing."""
+    ``dp_minplus_rows`` from K = 8 to 64 (J = 50 is the joint cell's) and
+    counts the trace; at K = 3, 7 and 65 it keeps the scan and counts
+    nothing."""
     B, chunk = 384, 40                      # shapes no other test traces
     f32 = jnp.float32
     step = jax.vmap(lambda *a: dp_fwd_frontier(*a),
@@ -109,26 +95,15 @@ def test_frontier_forward_takes_the_rows_kernel_by_width(one_chip, K):
         _sds(one_chip, (B, K), f32), _sds(one_chip, (B, K), jnp.bool_),
         _sds(one_chip, (B, K, K), f32),
         _sds(one_chip, (B,), jnp.int32)).compile()
-    rows = K == 50
+    rows = K in (8, 50, 64)
     assert ("tpu_custom_call" in compiled.as_text()) == rows
     assert DP_ROWS_TRACES[(K, chunk)] - before == int(rows)
-
-
-@pytest.mark.parametrize("salt", [None, 1])
-def test_slot_uniform_kernel_compiles_vmapped(one_chip, salt):
-    B = 64
-    kernel = functools.partial(slot_uniform_tc, salt=salt, interpret=False)
-    compiled = jax.jit(jax.vmap(kernel, in_axes=(0, None))).lower(
-        _sds(one_chip, (B, 2), jnp.uint32),
-        _sds(one_chip, (CHUNK,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_dp_fwd_chunk_xla_fits_one_chip(one_chip):
     B, K = 4096, 8
     f32 = jnp.float32
-    step = jax.vmap(functools.partial(dp_fwd_chunk, backend="xla"),
-                    in_axes=(0, None, 0, 0, 0, 0, 0, 0))
+    step = jax.vmap(dp_fwd_chunk, in_axes=(0, None, 0, 0, 0, 0, 0, 0))
     compiled = jax.jit(step).lower(
         _sds(one_chip, (B, K), f32), _sds(one_chip, (CHUNK,), jnp.int32),
         _sds(one_chip, (B, CHUNK), f32), _sds(one_chip, (B, CHUNK, K), f32),
